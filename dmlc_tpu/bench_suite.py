@@ -113,12 +113,13 @@ import argparse
 import json
 import os
 import sys
+import tempfile
 import time
 from typing import Dict, List, Optional
 
 import numpy as np
 
-_TMP = "/tmp/dmlc_tpu_bench_suite"
+_TMP = os.path.join(tempfile.gettempdir(), "dmlc_tpu_bench_suite")
 
 
 def _log(msg: str) -> None:
@@ -1399,6 +1400,7 @@ def bench_peer_hydrate(mb: int) -> Dict:
     env = {
         objstore.ENV_ROOT: f"{_TMP}.peer.objroot",
         objstore.ENV_LATENCY: "0.002",  # a modeled wire: GETs cost
+        "JAX_PLATFORMS": "cpu",  # the parent may hold the chip
         "PYTHONPATH": os.pathsep.join(
             [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
             + [p for p in os.environ.get("PYTHONPATH",
@@ -2044,6 +2046,7 @@ def bench_elastic_reshard(mb: int) -> Dict:
     env = {
         objstore.ENV_ROOT: root,
         objstore.ENV_LATENCY: "0.002",  # a modeled wire: GETs cost
+        "JAX_PLATFORMS": "cpu",  # the parent may hold the chip
         "PYTHONPATH": os.pathsep.join(
             [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
             + [p for p in os.environ.get("PYTHONPATH",
@@ -2511,10 +2514,13 @@ def bench_global_shuffle(mb: int) -> Dict:
     worker = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "bench_shuffle_worker.py")
     out_dir = tempfile.mkdtemp(prefix="dmlc_bench_shuffle_")
-    env = {"PYTHONPATH": os.pathsep.join(
-        [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
-        + [p for p in os.environ.get("PYTHONPATH",
-                                     "").split(os.pathsep) if p])}
+    env = {
+        "JAX_PLATFORMS": "cpu",  # the parent may hold the chip
+        "PYTHONPATH": os.pathsep.join(
+            [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
+            + [p for p in os.environ.get("PYTHONPATH",
+                                         "").split(os.pathsep) if p]),
+    }
     try:
         launch_local(2, [sys.executable, worker, uri, out_dir,
                          str(seed), str(window_bytes)],
@@ -2621,6 +2627,8 @@ def main(argv: Optional[List[str]] = None) -> None:
                          "run; configs must degrade gracefully, not "
                          "abort")
     args = ap.parse_args(argv)
+    from dmlc_tpu.utils.compile_cache import place_compile_cache
+    _log(f"compile cache: {place_compile_cache()}")
     chaos_plan = None
     chaos_injected0 = 0
     chaos_retries0: Dict[str, int] = {}
@@ -2656,6 +2664,7 @@ def main(argv: Optional[List[str]] = None) -> None:
     _prof_if_env()    # DMLC_TPU_PROFILE_HZ: /profile flamegraphs
     _ctl_if_env()     # DMLC_TPU_CONTROL: verdict-driven controller
     picks = [args.config] if args.config else sorted(CONFIGS)
+    failed = []
     for n in picks:
         name, fn = CONFIGS[n]
         _log(f"— config {n} ({name}), ~{args.mb} MB —")
@@ -2715,8 +2724,9 @@ def main(argv: Optional[List[str]] = None) -> None:
                                 if (d := v - chaos_retries0.get(k, 0))},
                 }
             _emit(out)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — the other configs run
             _emit({"config": name, "error": str(e)[:200]})
+            failed.append(n)
         finally:
             if chaos_plan is not None:
                 # advance the delta baselines on BOTH outcomes: a
@@ -2725,6 +2735,9 @@ def main(argv: Optional[List[str]] = None) -> None:
                 from dmlc_tpu.resilience import retry_counts
                 chaos_injected0 = chaos_plan.injected
                 chaos_retries0 = retry_counts()
+    if failed:
+        _log(f"configs failed: {failed}")
+        sys.exit(1)
 
 
 if __name__ == "__main__":

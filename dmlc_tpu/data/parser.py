@@ -100,7 +100,8 @@ def native_or(native_cls_name: str, python_cls, kwargs):
                 if engine == "native":
                     raise
         elif engine == "native":
-            raise DMLCError("native engine requested but not built")
+            from dmlc_tpu.native import get_lib
+            get_lib()  # raises, naming why the engine is unavailable
     elif engine == "native" and has_custom_split:
         raise DMLCError("native engine does not accept split_factory; "
                         "use engine='python' for injected splits")

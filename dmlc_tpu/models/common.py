@@ -30,10 +30,12 @@ def stable_bce_on_logits(margins: jnp.ndarray,
     """Per-row binary cross-entropy on logits, numerically stable.
 
     Labels may follow the ±1 (libsvm) or {0,1} convention: y = label > 0.
+    softplus is max(m, 0) + log1p(exp(-|m|)) with the true derivative,
+    sigmoid(m), at m == 0 too — written out, the max/abs subgradients
+    give 0 there, which is every row's margin at zero init.
     """
     y = (labels > 0).astype(jnp.float32)
-    return (jnp.maximum(margins, 0) - margins * y +
-            jnp.log1p(jnp.exp(-jnp.abs(margins))))
+    return jax.nn.softplus(margins) - margins * y
 
 
 class SparseModelBase:
@@ -104,10 +106,7 @@ class SparseModelBase:
             wsum = jax.lax.psum(wsum, axis)
             return _weighted_mean(lsum, wsum)
 
-        try:
-            from jax import shard_map
-        except ImportError:  # pre-0.4.35 jax: experimental namespace
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         # P() is a tree PREFIX covering the whole params dict; batch
         # columns shard on the data axis
         smapped = shard_map(

@@ -2,16 +2,18 @@
 
 The hot byte path (InputSplit sharding, text→CSR parse, prefetch) has a
 C++ implementation (native/src/*.cc) built as a shared library and bound
-via ctypes (no pybind11 in this environment). This module loads it lazily;
-when absent, the pure-Python golden engines are used with identical
-semantics.
+via ctypes (no pybind11 in this environment). This module builds it from
+the committed source on first use (``build.ensure_built``: missing, or
+stamped for another source or CPU) and loads it lazily. When it cannot
+be built or loaded, ``engine="auto"`` callers get the pure-Python golden
+engines with identical semantics and one warning; ``engine="native"``
+callers get the error.
 
-Build: ``python -m dmlc_tpu.native.build`` (uses g++ -O3 -march=native).
+Force a rebuild: ``python -m dmlc_tpu.native.build``.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 _lib = None
@@ -19,34 +21,34 @@ _tried = False
 _load_error: Optional[str] = None
 
 
-def _lib_path() -> str:
-    return os.path.join(os.path.dirname(__file__), "libdmlc_tpu.so")
-
-
 def native_available() -> bool:
     global _lib, _tried, _load_error
     if not _tried:
         _tried = True
-        path = _lib_path()
-        if os.path.exists(path):
-            try:
-                from dmlc_tpu.native import bindings
-                _lib = bindings.load(path)
-            except Exception as e:  # noqa: BLE001
-                # a present-but-unloadable .so (stale ABI, bad build) must
-                # not silently degrade to the Python engines: say why once,
-                # and keep the reason for get_lib()'s error
-                _lib = None
-                _load_error = str(e)
-                # all_ranks: the .so is HOST-local — in an ssh gang
-                # one host's stale build silently costs that rank ~10x
-                # while rank 0's loads fine, so every rank must say it
-                from dmlc_tpu.obs.log import warn_once
-                warn_once("native-engine-unusable",
-                          f"native engine present but unusable "
-                          f"({_load_error}); using Python engines",
-                          all_ranks=True)
+        try:
+            from dmlc_tpu.native import bindings, build
+            _lib = bindings.load(build.ensure_built())
+        except Exception as e:  # noqa: BLE001
+            # an engine that cannot be built or loaded must not silently
+            # degrade to the Python engines: say why once, and keep the
+            # reason for get_lib()'s error
+            _lib = None
+            _load_error = f"{e}{_stderr_tail(e)}"
+            # all_ranks: the .so is HOST-local — in an ssh gang one
+            # host's failed build silently costs that rank ~10x while
+            # rank 0's loads fine, so every rank must say it
+            from dmlc_tpu.obs.log import warn_once
+            warn_once("native-engine-unusable",
+                      f"native engine unusable ({_load_error}); "
+                      "using Python engines", all_ranks=True)
     return _lib is not None
+
+
+def _stderr_tail(e: Exception) -> str:
+    err = getattr(e, "stderr", None)
+    if isinstance(err, bytes):
+        err = err.decode(errors="replace")
+    return f": {err.strip()[-500:]}" if err else ""
 
 
 def get_lib():
@@ -54,8 +56,7 @@ def get_lib():
         from dmlc_tpu.utils.logging import DMLCError
         detail = (f" (load failed: {_load_error})" if _load_error
                   else "")
-        raise DMLCError("native engine not built; run "
-                        f"`python -m dmlc_tpu.native.build`{detail}")
+        raise DMLCError(f"native engine unavailable{detail}")
     return _lib
 
 

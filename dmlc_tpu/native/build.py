@@ -1,20 +1,31 @@
 """Build the native engine: ``python -m dmlc_tpu.native.build``.
 
 Compiles native/src/engine.cc into libdmlc_tpu.so next to this file
-(g++ -O3; zlib when the host has it — the Parquet GZIP page codec —
-no other external deps). The reference's CMake/Makefile build glue
-(CMakeLists.txt, make/dmlc.mk) maps to this single-step build plus
-pyproject.toml for the Python side.
+(g++ -O3 -march=native; zlib when the host has it — the Parquet GZIP
+page codec — no other external deps). The reference's CMake/Makefile
+build glue (CMakeLists.txt, make/dmlc.mk) maps to this single-step
+build plus pyproject.toml for the Python side.
+
+The ``.so`` is never committed: :func:`ensure_built` (called by
+``native_available()`` on first use) builds it from the committed
+source whenever it is missing or its stamp — a hash of the source, the
+compile command (zlib or not included) and this host's CPU
+(``-march=native``) — does not match. The stamp lives next to the
+``.so`` (``libdmlc_tpu.so.stamp``). Concurrent callers (pytest-xdist
+workers) serialize on a file lock; each build goes to a temp file that
+is renamed over the ``.so`` only after it passed the ABI probe, so no
+process ever maps a half-written library.
 
 The build ASSERTS the compiled engine's ABI (``dtp_version()``, 8
 since the columnar-page + image-payload decode) equals
 ``bindings.ABI_VERSION`` in a subprocess probe — a stale source tree
-or .so fails the BUILD loudly instead of engine="auto" callers
-silently falling back to the python golden at first use.
+fails the BUILD loudly instead of at first use.
 """
 
 from __future__ import annotations
 
+import fcntl
+import hashlib
 import os
 import subprocess
 import sys
@@ -58,26 +69,106 @@ def zlib_flags() -> list:
     return list(_ZLIB_FLAGS)
 
 
-def build(verbose: bool = True) -> str:
-    cmd = [
-        "g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-        "-pthread", "-Wall", "-Wextra",
-        SRC, "-o", OUT,
-    ] + zlib_flags()
+def _flags() -> list:
+    return ["-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
+            "-pthread", "-Wall", "-Wextra"]
+
+
+def _cpu_signature() -> str:
+    """What ``-march=native`` compiles for: the host's CPU flags. A
+    library built on another machine (a copied checkout) must not be
+    loaded here — it may use instructions this CPU lacks."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line.strip()
+    except OSError:
+        pass
+    import platform
+    return platform.machine() + platform.processor()
+
+
+def stamp_for(src: str = SRC) -> str:
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_flags() + zlib_flags()).encode())
+    h.update(_cpu_signature().encode())
+    return h.hexdigest()
+
+
+def _stamp_path(out: str) -> str:
+    return out + ".stamp"
+
+
+def is_current(src: str = SRC, out: str = OUT) -> bool:
+    """True when ``out`` exists and was built from ``src`` as it is
+    now, with today's flags, on this CPU."""
+    try:
+        with open(_stamp_path(out)) as f:
+            recorded = f.read().strip()
+    except OSError:
+        return False
+    return os.path.exists(out) and recorded == stamp_for(src)
+
+
+def build(verbose: bool = True, src: str = SRC, out: str = OUT) -> str:
+    """Compile ``src`` into ``out`` unconditionally (under the lock)."""
+    with _locked(out):
+        return _build_locked(verbose, src, out)
+
+
+def ensure_built(src: str = SRC, out: str = OUT) -> str:
+    """Build ``out`` from ``src`` unless the stamp says it is current.
+    Safe to call from many processes at once: one builds, the others
+    wait on the lock and then find the stamp current."""
+    if is_current(src, out):
+        return out
+    with _locked(out):
+        if not is_current(src, out):
+            _build_locked(False, src, out)
+    return out
+
+
+class _locked:
+    def __init__(self, out: str):
+        self._path = out + ".lock"
+
+    def __enter__(self):
+        self._fd = os.open(self._path, os.O_RDWR | os.O_CREAT, 0o644)
+        fcntl.flock(self._fd, fcntl.LOCK_EX)
+        return self
+
+    def __exit__(self, *exc):
+        fcntl.flock(self._fd, fcntl.LOCK_UN)
+        os.close(self._fd)
+
+
+def _build_locked(verbose: bool, src: str, out: str) -> str:
+    stamp = stamp_for(src)
+    tmp = f"{out}.tmp{os.getpid()}"
+    cmd = ["g++"] + _flags() + [src, "-o", tmp] + zlib_flags()
     if verbose:
         print("+", " ".join(cmd))
-    subprocess.run(cmd, check=True)
-    _check_abi(OUT)
-    return OUT
+    try:
+        subprocess.run(cmd, check=True, capture_output=not verbose)
+        _check_abi(tmp)
+        # the .so first, then its stamp: a reader that sees the new
+        # stamp always finds the new library behind it
+        os.replace(tmp, out)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    with open(_stamp_path(out) + ".tmp", "w") as f:
+        f.write(stamp + "\n")
+    os.replace(_stamp_path(out) + ".tmp", _stamp_path(out))
+    return out
 
 
 def _check_abi(path: str) -> None:
     """Fail the build — loudly, at build time — when the freshly
-    compiled engine does not speak the ABI the bindings expect. Without
-    this a stale source tree produces a .so that bindings.load()
-    rejects at first use, and engine="auto" callers silently fall back
-    to the python golden: the perf regression shows up in BENCH numbers
-    instead of in the build.
+    compiled engine does not speak the ABI the bindings expect.
 
     The probe runs in a SUBPROCESS: dlopen in this process would
     resolve the path to an already-mapped old copy (a REPL that used

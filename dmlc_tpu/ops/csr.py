@@ -120,10 +120,7 @@ def sharded_spmv(batch, weights, mesh, axis: str = "data"):
     learners (per-device partial results, psum-able gradients).
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # pre-0.4.35 jax: experimental namespace
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     row_bucket = batch["offset"].shape[1] - 1
 

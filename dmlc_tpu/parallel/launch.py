@@ -217,6 +217,12 @@ def launch_local(num_workers: int, command: Sequence[str],
                  heartbeat_grace_s: Optional[float] = None) -> List[int]:
     """Run N worker processes on this host (reference: local.py).
 
+    It binds no chip to a worker. On a TPU host a chip belongs to one
+    process at a time, so start one chip-holding process per host and
+    nothing more: every other worker says ``JAX_PLATFORMS=cpu`` in
+    ``env``, and the launcher's own process stays off the chip while
+    a chip-holding worker runs.
+
     With ``num_servers > 0`` (reference: dmlc-submit --num-servers +
     PSTracker), additionally spawns ONE scheduler and ``num_servers``
     server processes running the same command under the PS env contract

@@ -12,23 +12,22 @@ device"):
    (recordio_device_batches: zero host-side record copy with the native
    engine), and reduce over the payload on device.
 
-Runs on an 8-virtual-device CPU mesh by default; on a TPU host the same
-code lands the batches in HBM.
+Runs on whatever JAX finds: on a TPU host the batches land in HBM; under
+``JAX_PLATFORMS=cpu`` it runs on an 8-virtual-device CPU mesh.
 """
 
 import os
 import struct
 
-if "JAX_PLATFORMS" not in os.environ:
-    os.environ["JAX_PLATFORMS"] = "cpu"
+# virtual devices for a CPU run (read at backend init; no effect on TPU)
+if "xla_force_host_platform_device_count" not in os.environ.get(
+        "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                " --xla_force_host_platform_device_count=8")
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"].split(",")[0])
 
 from dmlc_tpu.io import RECORDIO_MAGIC, RecordIOWriter, create_stream
 from dmlc_tpu.io.stream import create_seek_stream_for_read

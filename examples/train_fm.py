@@ -9,11 +9,13 @@ SparseFFMModel, which additionally consumes the parsed field[] column
 follows a pure INTERACTION rule (label = XOR over feature pairs), which
 a linear model provably cannot fit and the FM's pairwise term can.
 
-Runs anywhere: on a CPU-only host it uses 8 virtual devices.
+Runs on whatever JAX finds; on the CPU (JAX_PLATFORMS=cpu) it uses 8
+virtual devices.
 """
 
 import os
 
+# virtual devices for a CPU run (read at backend init; no effect on TPU)
 if "xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
@@ -23,14 +25,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get(
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
-
-if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-else:
-    try:
-        jax.devices()
-    except RuntimeError:  # preset platform unavailable -> CPU fallback
-        jax.config.update("jax_platforms", "cpu")
 
 from dmlc_tpu.models import SparseFFMModel, SparseFMModel  # noqa: E402
 from dmlc_tpu.parallel import ShardedRowBlockIter  # noqa: E402

@@ -7,11 +7,13 @@ sharded ingest pads it (-1) into device batches, and SparseRankingModel
 shard_map. The data is query-grouped with graded relevance from a
 hidden scorer, so pairwise accuracy provably rises.
 
-Runs anywhere: on a CPU-only host it uses 8 virtual devices.
+Runs on whatever JAX finds; on the CPU (JAX_PLATFORMS=cpu) it uses 8
+virtual devices.
 """
 
 import os
 
+# virtual devices for a CPU run (read at backend init; no effect on TPU)
 if "xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
@@ -21,14 +23,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get(
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
-
-if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
-    jax.config.update("jax_platforms", "cpu")
-else:
-    try:
-        jax.devices()
-    except RuntimeError:  # preset platform unavailable -> CPU fallback
-        jax.config.update("jax_platforms", "cpu")
 
 from dmlc_tpu.models import SparseRankingModel  # noqa: E402
 from dmlc_tpu.parallel import ShardedRowBlockIter  # noqa: E402

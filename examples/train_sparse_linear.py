@@ -12,17 +12,16 @@ a learner, reference: test/dataiter_test.cc + docs):
      psum-reduced logistic loss, SGD on replicated params
   4. ShardedCheckpoint save / restore, then training resumes
 
-Runs anywhere: on a CPU-only host it uses 8 virtual devices (set before
-jax import). On a TPU slice, drop the XLA_FLAGS override and launch one
-process per host (python -m dmlc_tpu.parallel.launch --help).
+Runs on whatever JAX finds: the chips of a TPU host, or the CPU with 8
+virtual devices when run as ``JAX_PLATFORMS=cpu python ...``. On a TPU
+slice, launch one process per host (python -m dmlc_tpu.parallel.launch
+--help).
 """
 
 import os
 import time
 
-# default to an 8-virtual-device CPU mesh when the environment hasn't
-# picked a working accelerator platform itself (XLA_FLAGS is read at
-# backend init, so setting it here still takes effect)
+# virtual devices for a CPU run (read at backend init; no effect on TPU)
 if "xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
@@ -33,16 +32,6 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
-
-if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
-    # env var alone can be overridden by an installed accelerator plugin;
-    # the config update is authoritative (same pattern as tests/conftest.py)
-    jax.config.update("jax_platforms", "cpu")
-else:
-    try:
-        jax.devices()
-    except RuntimeError:  # preset platform unavailable -> CPU fallback
-        jax.config.update("jax_platforms", "cpu")
 
 from dmlc_tpu.models import SparseLinearModel  # noqa: E402
 from dmlc_tpu.parallel import ShardedRowBlockIter  # noqa: E402

@@ -4,7 +4,6 @@ propagation, float-parse contract."""
 
 import os
 import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -16,15 +15,7 @@ from dmlc_tpu.utils.logging import DMLCError
 
 def _ensure_native() -> bool:
     from dmlc_tpu import native
-    if native.native_available():
-        return True
-    try:
-        subprocess.run([sys.executable, "-m", "dmlc_tpu.native.build"],
-                       check=True, capture_output=True, timeout=300)
-    except Exception:
-        return False
-    native._tried = False  # re-probe after build
-    return native.native_available()
+    return native.native_available()  # builds from engine.cc if needed
 
 
 pytestmark = pytest.mark.skipif(not _ensure_native(),
@@ -1091,3 +1082,48 @@ class TestTSAN:
         assert "WARNING: ThreadSanitizer" not in report, report[-4000:]
         assert run.returncode == 0, report[-4000:]
         assert "scenarios completed" in run.stdout
+
+
+def test_build_stamp_rebuilds_on_source_change(tmp_path, monkeypatch):
+    """The .so is rebuilt exactly when its source or link line changes,
+    once for any number of concurrent callers, and always atomically
+    (ensure_built on a stand-in source: same flags, lock and ABI
+    probe)."""
+    import threading
+
+    from dmlc_tpu.native import build
+    from dmlc_tpu.native.bindings import ABI_VERSION
+    src = tmp_path / "engine.cc"
+    out = str(tmp_path / "libdmlc_tpu.so")
+    src.write_text('extern "C" int dtp_version() { return %d; }\n'
+                   % ABI_VERSION)
+    builds = []
+    real = build._build_locked
+
+    def counting(*a):
+        builds.append(a)
+        return real(*a)
+
+    monkeypatch.setattr(build, "_build_locked", counting)
+    threads = [threading.Thread(target=build.ensure_built,
+                                args=(str(src), out)) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert len(builds) == 1 and build.is_current(str(src), out)
+    ino = os.stat(out).st_ino
+    build.ensure_built(str(src), out)  # unchanged source: no build
+    assert len(builds) == 1
+    src.write_text(src.read_text() + "// changed\n")
+    assert not build.is_current(str(src), out)
+    build.ensure_built(str(src), out)
+    assert len(builds) == 2 and build.is_current(str(src), out)
+    assert os.stat(out).st_ino != ino  # renamed over, not rewritten
+    assert sorted(os.listdir(tmp_path)) == [
+        "engine.cc", "libdmlc_tpu.so", "libdmlc_tpu.so.lock",
+        "libdmlc_tpu.so.stamp"]
+    # zlib appearing on (or leaving) the host changes the link line
+    other = ["-DDTP_NO_ZLIB"] if build.zlib_flags() == ["-lz"] else ["-lz"]
+    monkeypatch.setattr(build, "_ZLIB_FLAGS", other)
+    assert not build.is_current(str(src), out)

@@ -785,12 +785,18 @@ class TestBenchChaos:
         monkeypatch.setattr(bench_suite, "CONFIGS",
                             {98: ("doomed", doomed),
                              99: ("chaos_probe", chaos_probe)})
-        bench_suite.main([
-            "--mb", "1", "--cold",
-            "--chaos",
-            "site=bench.doomed,fault=ioerror;"
-            "site=io.stream.open,fault=ioerror,times=1;"
-            "site=io.stream.read,fault=ioerror,times=1"])
+        # the suite's compile cache would outlive this test in the worker
+        monkeypatch.setattr("dmlc_tpu.utils.compile_cache."
+                            "place_compile_cache", lambda: "")
+        with pytest.raises(SystemExit) as ei:
+            bench_suite.main([
+                "--mb", "1", "--cold",
+                "--chaos",
+                "site=bench.doomed,fault=ioerror;"
+                "site=io.stream.open,fault=ioerror,times=1;"
+                "site=io.stream.read,fault=ioerror,times=1"])
+        # every config ran, and the failed one fails the suite
+        assert ei.value.code == 1
         out = [json.loads(line) for line in
                capsys.readouterr().out.splitlines() if line.strip()]
         assert len(out) == 2
