@@ -272,7 +272,7 @@ def main() -> None:
         # engine's consumer-side assemble_ns on the fused native rung
         # (where parse+assemble are ONE stage), the measured pad_single
         # time on the python rung (its own stage), plus the host
-        # staging copies (device.assemble spans) when staging runs.
+        # staging copies (to_device.stage spans) when staging runs.
         # Scanned across stages: the fused path folds assembly into
         # stages[0], the fallback carries it on its own stage.
         t_asm = dx.get("staging_assemble_s", 0.0)
@@ -294,11 +294,10 @@ def main() -> None:
     budget_s = float(os.environ.get("DMLC_TPU_BENCH_BUDGET_S", "60"))
     min_epochs = max(3, int(os.environ.get("DMLC_TPU_BENCH_MIN_EPOCHS", "5")))
     # DMLC_TPU_TRACE=<dir>: dump a jax.profiler device timeline of one
-    # epoch (obs.trace.jax_trace) for offline inspection
+    # epoch (the program's spans ride on it) for offline inspection
     trace_dir = os.environ.get("DMLC_TPU_TRACE")
     if trace_dir:
-        from dmlc_tpu.obs.trace import jax_trace
-        with jax_trace("bench_epoch", log_dir=trace_dir):
+        with jax.profiler.trace(trace_dir):
             epoch()
         log(f"jax.profiler trace written to {trace_dir}")
 

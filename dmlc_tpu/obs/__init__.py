@@ -4,8 +4,8 @@ One place to see where time went and why a pull wedged, across the
 Python and native layers (docs/observability.md):
 
 - :mod:`~dmlc_tpu.obs.trace` — thread-aware span/instant/counter ring
-  buffer, near-zero cost when off; the repo's ONE span API (the old
-  ``utils.profiler`` is a shim over it);
+  buffer, near-zero cost when off, mirrored onto the ``jax.profiler``
+  timeline while one collects; the repo's ONE span API;
 - :mod:`~dmlc_tpu.obs.export` — Chrome/Perfetto trace-event JSON
   export + gang trace merging;
 - :mod:`~dmlc_tpu.obs.metrics` — counters/gauges/histograms plus the
@@ -54,14 +54,13 @@ from dmlc_tpu.obs.serve import (
     StatusServer, render_prometheus, scrape_gang, serve,
 )
 from dmlc_tpu.obs.trace import (
-    Profiler, StageStats, TraceRecorder, counter, instant, jax_trace,
-    profiler, span, start, stop, trace_to,
+    TraceRecorder, counter, instant, span, start, stop, trace_to,
 )
 from dmlc_tpu.obs.watchdog import Watchdog
 
 __all__ = [
     "TraceRecorder", "span", "instant", "counter", "start", "stop",
-    "trace_to", "Profiler", "StageStats", "profiler", "jax_trace",
+    "trace_to",
     "chrome_events", "write_chrome", "merge_chrome_files",
     "MetricsRegistry", "REGISTRY", "Counter", "Gauge", "Histogram",
     "merge_snapshots", "METRICS_SCHEMA",

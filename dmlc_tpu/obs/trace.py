@@ -15,33 +15,38 @@ hot-path contract is:
   the overwritten ones);
 - **wall-anchored timestamps**: ts = wall-clock at recorder start plus
   a perf_counter delta, so traces from different processes of one gang
-  merge onto a single timeline (``obs.export.merge_chrome_files``).
+  merge onto a single timeline (``obs.export.merge_chrome_files``);
+- **mirrored onto the profiler's clock**: while a ``jax.profiler``
+  session collects, each span :func:`begin` opens is ALSO a live
+  ``TraceMe`` on the thread doing the work, carrying its category as
+  the ``cat`` argument, so it lands in the same xplane as the device's
+  operations and a device idle gap can be put down to the program span
+  open across it. Off, a site pays one ``is_enabled()`` check and one
+  small object, and builds no ``TraceMe``. A live span opens and
+  closes within one ``next()`` of the generator that does the work,
+  never across a ``yield``.
 
 Export to Chrome/Perfetto trace-event JSON lives in
 :mod:`dmlc_tpu.obs.export`; ``trace_to(path)`` is the one-liner.
-
-The pre-obs ``utils.profiler`` API (named-stage accumulator + jax
-device-trace context) is folded in here: :class:`Profiler` keeps its
-calls/seconds/bytes aggregation semantics but every ``stage()`` now
-ALSO emits a span into the active recorder, so there is one span
-vocabulary, not two. ``utils/profiler.py`` is a deprecation shim.
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass
-from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+# the profiler's native span (jax.profiler.TraceAnnotation subclasses it)
+from jaxlib._profiler import TraceMe as _TraceMe
 
 __all__ = [
     "TraceRecorder", "active", "start", "stop", "trace_to",
-    "trace_if_env", "span", "instant", "counter",
+    "trace_if_env", "span", "begin", "OpenSpan", "instant", "counter",
     "set_fallback", "clear_fallback", "fallback",
     "CAT_RPC_CLIENT", "CAT_RPC_SERVER",
-    "Profiler", "StageStats", "profiler", "jax_trace",
 ]
 
 # span categories for the cross-process RPC plane (obs.rpc): a client
@@ -255,20 +260,71 @@ def trace_to(path: str, capacity: int = 1 << 20) -> Iterator[TraceRecorder]:
             write_chrome(rec, path)
 
 
+# True while a jax.profiler session collects (one native call, no
+# allocation)
+_profiling = _TraceMe.is_enabled
+_now = time.perf_counter
+
+
+def _live(name: str, cat: str):
+    """``name`` opened on the profiler's timeline, on the calling
+    thread (check :func:`_profiling` first: off, build nothing)."""
+    tm = _TraceMe(name, cat=cat)
+    tm.__enter__()
+    return tm
+
+
+class OpenSpan:
+    """A span :func:`begin` opened. :meth:`end` closes it on the active
+    recorder and the profiler's timeline alike, and returns its
+    seconds: the one perf_counter pair a site's probe reads too."""
+
+    __slots__ = ("name", "cat", "args", "rec", "tm", "t0")
+
+    def __init__(self, name: str, cat: str = "",
+                 args: Optional[dict] = None):
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self.rec = _recorder
+        self.tm = _live(name, cat) if _profiling() else None
+        self.t0 = _now()
+
+    def end(self, name: Optional[str] = None, **meta: Any) -> float:
+        """Close on the thread that opened it. ``name`` renames the
+        recorder's span (the profiler's keeps the name it opened with);
+        ``meta`` goes on both, as arguments."""
+        dt = _now() - self.t0
+        if self.tm is not None:
+            if meta:
+                self.tm.set_metadata(**meta)
+            self.tm.__exit__(None, None, None)
+        if self.rec is not None:
+            args = self.args
+            if meta:
+                args = {**args, **meta} if args else meta
+            self.rec.complete(name or self.name, self.t0, dt, self.cat, args)
+        return dt
+
+
+# begin(name, cat="", args=None) -> OpenSpan: open a span on the active
+# recorder and, while a profiler collects, on its timeline; close it
+# with OpenSpan.end() in a ``finally``. With neither on it only times:
+# one global read, one is_enabled() check, its perf_counter pair. The
+# hot path's form of span(): no generator frame, and no call beyond
+# the constructor's.
+begin = OpenSpan
+
+
 @contextlib.contextmanager
 def span(name: str, cat: str = "",
          args: Optional[dict] = None) -> Iterator[None]:
-    """Record the block as one complete span (no-op when tracing is
-    off — the recorder check costs one global read)."""
-    rec = _recorder
-    if rec is None:
-        yield
-        return
-    t0 = time.perf_counter()
+    """Record the block as one complete span (see :func:`begin`)."""
+    sp = begin(name, cat, args)
     try:
         yield
     finally:
-        rec.complete(name, t0, time.perf_counter() - t0, cat, args)
+        sp.end()
 
 
 def instant(name: str, cat: str = "", args: Optional[dict] = None) -> None:
@@ -283,84 +339,30 @@ def counter(name: str, values: Dict[str, Any], cat: str = "") -> None:
         rec.counter(name, values, cat)
 
 
-# ---------------------------------------------------------------- profiler
-# The folded utils.profiler surface: same aggregation semantics, spans
-# now flow through the recorder above.
-
-@dataclass
-class StageStats:
-    calls: int = 0
-    seconds: float = 0.0
-    bytes: int = 0
-    items: int = 0
-
-    @property
-    def gb_per_sec(self) -> float:
-        return self.bytes / self.seconds / 1e9 if self.seconds else 0.0
+# -- garbage collections as spans: ``python.gc``, one per collection,
+# on the thread that triggered it (a collection runs to its end on that
+# thread, so start/stop pair up), on the profiler's timeline only: a
+# collection can start inside any allocation, a recorder's locked
+# sections included, so the hook never touches a recorder. Off, each
+# phase costs one check.
+_gc_open = None
 
 
-class Profiler:
-    """Named-stage accumulator; thread-safe. Each ``stage()`` also
-    emits a span into the active TraceRecorder (cat "profiler")."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._stages: Dict[str, StageStats] = {}
-        self.enabled = True
-
-    @contextlib.contextmanager
-    def stage(self, name: str, nbytes: int = 0,
-              items: int = 0) -> Iterator[None]:
-        if not self.enabled:
-            yield
-            return
-        rec = _recorder
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            if rec is not None:
-                rec.complete(name, t0, dt, "profiler",
-                             {"bytes": nbytes, "items": items}
-                             if nbytes or items else None)
-            self.add(name, seconds=dt, nbytes=nbytes, items=items,
-                     _calls=1)
-
-    def add(self, name: str, seconds: float = 0.0, nbytes: int = 0,
-            items: int = 0, _calls: int = 1) -> None:
-        with self._lock:
-            st = self._stages.setdefault(name, StageStats())
-            st.calls += _calls
-            st.seconds += seconds
-            st.bytes += nbytes
-            st.items += items
-
-    def stats(self) -> Dict[str, StageStats]:
-        with self._lock:
-            return dict(self._stages)
-
-    def reset(self) -> None:
-        with self._lock:
-            self._stages.clear()
-
-    def report(self) -> str:
-        lines = [f"{'stage':<24}{'calls':>8}{'sec':>10}{'GB':>10}"
-                 f"{'GB/s':>10}{'items':>10}"]
-        for name, st in sorted(self.stats().items()):
-            lines.append(
-                f"{name:<24}{st.calls:>8}{st.seconds:>10.3f}"
-                f"{st.bytes / 1e9:>10.3f}{st.gb_per_sec:>10.3f}"
-                f"{st.items:>10}")
-        return "\n".join(lines)
+def _gc_span(phase: str, info: Dict[str, Any]) -> None:
+    global _gc_open
+    if phase == "start":
+        if _profiling():
+            _gc_open = _live("python.gc", "python")
+        return
+    tm, _gc_open = _gc_open, None
+    if tm is not None:
+        tm.set_metadata(generation=info.get("generation"),
+                        collected=info.get("collected"))
+        tm.__exit__(None, None, None)
 
 
-profiler = Profiler()  # process-global default instance
-
-# the profiler's named-stage aggregates join the one metrics snapshot
-from dmlc_tpu.obs.metrics import REGISTRY as _REGISTRY  # noqa: E402
-
-_REGISTRY.register("profiler", profiler, Profiler.stats)
+if _gc_span not in gc.callbacks:
+    gc.callbacks.append(_gc_span)
 
 
 @contextlib.contextmanager
@@ -381,18 +383,3 @@ def trace_if_env(trace_dir: Optional[str] = None) -> Iterator[None]:
     os.makedirs(d, exist_ok=True)
     with trace_to(os.path.join(d, f"trace-{tag}.json")):
         yield
-
-
-@contextlib.contextmanager
-def jax_trace(name: str, log_dir: Optional[str] = None) -> Iterator[None]:
-    """Wrap a region in a jax.profiler trace (device timeline) when
-    log_dir is given, else a named TraceAnnotation; always also feeds
-    the process profiler (and through it the active recorder)."""
-    import jax
-    with profiler.stage(name):
-        if log_dir is not None:
-            with jax.profiler.trace(log_dir):
-                yield
-        else:
-            with jax.profiler.TraceAnnotation(name):
-                yield

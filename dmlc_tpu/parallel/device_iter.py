@@ -12,8 +12,9 @@ real accelerators; plain page-aligned numpy here), the transfer is
 enqueued FROM the slot, and the source arrays are free the moment the
 copy lands — so a leased native-engine block returns to its arena while
 its bytes are still in flight, and batch N's H2D transfer overlaps
-batch N+1's assembly. Each stage copy emits a ``device.assemble`` span
-and each transfer a ``device.xfer`` span (enqueue → ready) on the same
+batch N+1's assembly. Each stage copy emits a ``to_device.stage`` span
+(also on the profiler's timeline while one collects) and each transfer
+a ``device.xfer`` span (enqueue → ready, recorder only) on the same
 timeline, so the overlap is visible in one Perfetto trace; the
 ``device.staging`` gauge tracks slots in flight.
 """
@@ -70,27 +71,24 @@ class HostStaging:
         return True
 
     def stage(self, arrs: Dict[str, Any]) -> Dict[str, np.ndarray]:
-        """Copy ``arrs`` into a staging slot (``device.assemble`` span);
+        """Copy ``arrs`` into a staging slot (``to_device.stage`` span);
         the source arrays are dead to this pool after the call."""
-        t0 = time.perf_counter()
-        slot = None
-        for i, s in enumerate(self._free):
-            if self._matches(s, arrs):
-                slot = self._free.pop(i)
-                break
-        if slot is None:
-            slot = {k: np.empty(np.shape(v), np.asarray(v).dtype)
-                    for k, v in arrs.items()}
-        for k, v in arrs.items():
-            np.copyto(slot[k], v)
-        dt = time.perf_counter() - t0
-        self.assemble_s += dt
+        sp = _trace.begin("to_device.stage", "transfer")
+        try:
+            slot = None
+            for i, s in enumerate(self._free):
+                if self._matches(s, arrs):
+                    slot = self._free.pop(i)
+                    break
+            if slot is None:
+                slot = {k: np.empty(np.shape(v), np.asarray(v).dtype)
+                        for k, v in arrs.items()}
+            for k, v in arrs.items():
+                np.copyto(slot[k], v)
+        finally:
+            self.assemble_s += sp.end(in_flight=self.in_flight + 1)
         self.in_flight += 1
         _METRICS.gauge("device.staging").set(self.in_flight)
-        rec = _trace.active()
-        if rec is not None:
-            rec.complete("device.assemble", t0, dt, "transfer",
-                         {"in_flight": self.in_flight})
         return slot
 
     def release(self, slot: Dict[str, np.ndarray]) -> None:
@@ -137,7 +135,7 @@ def device_prefetch(host_batches: Iterable[Dict[str, Any]], size: int = 2,
     ``staging=True`` (dict batches only) routes every batch through a
     reusable :class:`HostStaging` pair: the source arrays are free the
     moment the staged copy lands, ≥2 batches stay in flight, and each
-    yielded batch is blocked-until-ready with ``device.assemble`` /
+    yielded batch is blocked-until-ready with ``to_device.stage`` /
     ``device.xfer`` spans proving the copy/transfer overlap.
     """
     queue: collections.deque = collections.deque()
@@ -176,7 +174,7 @@ def device_prefetch(host_batches: Iterable[Dict[str, Any]], size: int = 2,
         rec = _trace.active()
         if rec is not None:
             # the full async window, enqueue → ready: overlaps the NEXT
-            # batch's device.assemble span when staging does its job
+            # batch's to_device.stage span when staging does its job
             rec.complete("device.xfer", t_enq,
                          time.perf_counter() - t_enq, "transfer")
         pool.release(slot)

@@ -37,6 +37,7 @@ from dmlc_tpu.data.padding import (  # noqa: F401 — re-exported: the
     ensure_schema, pad_to_bucket, stack_padded_rows,
 )
 from dmlc_tpu.data.rowblock import RowBlock
+from dmlc_tpu.obs import trace as _trace
 from dmlc_tpu.utils.logging import (
     DMLCError, check, check_eq,
 )
@@ -144,6 +145,14 @@ class ShardedRowBlockIter:
     and re-earns replay by teeing the clean re-parse. The first epoch
     of a single-process "auto" run streams (fast first batch); its
     epoch 2 re-parses and tees; epochs 3+ replay.
+
+    Spans (obs.trace; on the profiler's timeline while one collects),
+    on whichever thread assembles a round: ``shard.parse`` (one block
+    from each part's parser), ``shard.stack`` (pad and stack) and
+    ``shard.put`` (the global batch). ``last_counts`` holds the host
+    (rows, nnz) of the round last yielded, ``stack_s`` the epoch's
+    pad-and-stack seconds, and ``engine_stats()`` the parts' engine
+    counters.
     """
 
     def __init__(self, uri: str, mesh: Mesh, format: Optional[str] = None,
@@ -208,6 +217,8 @@ class ShardedRowBlockIter:
         # budget; memory accumulation would be redundant copying)
         self._serve_queue = None  # live serve ThreadedIter (probes)
         self._serve_stats: Optional[Dict[str, float]] = None
+        self.last_counts = (0, 0)
+        self.stack_s = 0.0
         # serve-side prefetch lookahead (rounds assembled ahead of the
         # consumer); dmlc_tpu.pipeline exposes it as an autotuner knob
         self.prefetch_depth = 2
@@ -261,7 +272,7 @@ class ShardedRowBlockIter:
             f"shard/{_os.path.basename(uri.split('?', 1)[0])}",
             self, ShardedRowBlockIter._metrics)
 
-    def _first_epoch_batches(self) -> Iterator[Dict[str, jax.Array]]:
+    def _first_epoch_batches(self) -> Iterator[tuple]:
         """Epoch 1: agree on rounds-per-epoch across processes.
 
         Fast path (one collective): parse AND pad the local parts into
@@ -311,7 +322,7 @@ class ShardedRowBlockIter:
                     if r < len(c):
                         c[r] = None  # the tee owns the blocks now
                 tee.add_row(row)
-                return make_global_batch(stacked, self.mesh, self.axis)
+                return self._put(stacked)
 
             # pad+stack+assembly for round r+1 runs on a background
             # thread while the consumer works on round r: claws back
@@ -366,7 +377,7 @@ class ShardedRowBlockIter:
             tee.close()
 
     def _replay_store(self, store: "ShardedRowBlockIter._RoundStore"
-                      ) -> Iterator[Dict[str, jax.Array]]:
+                      ) -> Iterator[tuple]:
         """Serve an epoch from retained raw rounds (memory or pages):
         zero parsing — the serve-prefetch thread pads, stacks and
         enqueues transfers one round ahead of the consumer. One
@@ -383,8 +394,7 @@ class ShardedRowBlockIter:
             row = next(rows, None)
             if row is None:
                 return None
-            return make_global_batch(self._assemble_stacked(row),
-                                     self.mesh, self.axis)
+            return self._assemble(row)
 
         try:
             yield from self._prefetch_serve(make)
@@ -616,7 +626,7 @@ class ShardedRowBlockIter:
             self._spill_dir or default_spill_dir(),
             f"rounds-{key}-p{os.getpid()}-{next(self._SPILL_SEQ)}.pages")
 
-    def _prefetch_serve(self, make_next) -> Iterator[Dict[str, jax.Array]]:
+    def _prefetch_serve(self, make_next) -> Iterator[tuple]:
         """Serve batches from a background producer, one round ahead:
         assembly/transfer of round r+1 overlaps the consumer's work on
         round r. The live queue is exposed as ``_serve_queue`` while an
@@ -760,6 +770,13 @@ class ShardedRowBlockIter:
         return its, [False] * len(its), [0] * len(its)
 
     def _next_row(self, its, done, counts) -> List[RowBlock]:
+        sp = _trace.begin("shard.parse", "shard")
+        try:
+            return self._next_row_blocks(its, done, counts)
+        finally:
+            sp.end()
+
+    def _next_row_blocks(self, its, done, counts) -> List[RowBlock]:
         row = []
         for i, it in enumerate(its):
             if done[i]:
@@ -902,13 +919,43 @@ class ShardedRowBlockIter:
         # pad_to_bucket dicts + np.stack — on the replay serve thread
         # this halves the memcpy per round, which IS the page-tier
         # throughput cap
-        return stack_padded_rows(blocks, self.row_bucket,
-                                 self.nnz_bucket, self._has_qid,
-                                 self._has_field)
+        sp = _trace.begin("shard.stack", "shard")
+        try:
+            return stack_padded_rows(blocks, self.row_bucket,
+                                     self.nnz_bucket, self._has_qid,
+                                     self._has_field)
+        finally:
+            self.stack_s += sp.end()
 
-    def _assemble(self, blocks: List[RowBlock]) -> Dict[str, jax.Array]:
-        return make_global_batch(self._assemble_stacked(blocks),
-                                 self.mesh, self.axis)
+    def _put(self, stacked: Dict[str, np.ndarray]) -> tuple:
+        """(global batch, host (rows, nnz)) of one stacked round."""
+        counts = (int(stacked["num_rows"].sum()),
+                  int(stacked["num_nnz"].sum()))
+        sp = _trace.begin("shard.put", "shard")
+        try:
+            return make_global_batch(stacked, self.mesh, self.axis), counts
+        finally:
+            sp.end()
+
+    def _assemble(self, blocks: List[RowBlock]) -> tuple:
+        return self._put(self._assemble_stacked(blocks))
+
+    def engine_stats(self) -> Optional[Dict[str, Any]]:
+        """The native engine counters summed over this process's part
+        parsers (``wall_ns``: the slowest part's), beside ``stack_s``;
+        None when no part parses natively."""
+        engines = []
+        for p in self._parsers:
+            stats_fn = getattr(p, "stats", None)
+            if stats_fn is not None:
+                try:
+                    engines.append(stats_fn())
+                except Exception:  # noqa: BLE001 — telemetry must not kill
+                    pass
+        if not engines:
+            return None
+        return {k: (max if k == "wall_ns" else sum)(e[k] for e in engines)
+                for k in engines[0]}
 
     def _note_tier(self, tier: str) -> None:
         """Stamp the tier serving this epoch; the per-iterator obs
@@ -926,6 +973,13 @@ class ShardedRowBlockIter:
                 "prefetch_depth": self.prefetch_depth}
 
     def __iter__(self) -> Iterator[Dict[str, jax.Array]]:
+        self.stack_s = 0.0
+        for batch, counts in self._epoch():
+            self.last_counts = counts
+            yield batch
+
+    def _epoch(self) -> Iterator[tuple]:
+        """One epoch of (global batch, host counts) pairs."""
         if self._rounds_per_epoch is None:
             self._note_tier("parse")
             yield from self._first_epoch_batches()

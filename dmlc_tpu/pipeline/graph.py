@@ -43,7 +43,7 @@ from dmlc_tpu.obs import watchdog as _watchdog
 from dmlc_tpu.obs.metrics import REGISTRY as _METRICS
 from dmlc_tpu.pipeline.autotune import Autotuner, Knob
 from dmlc_tpu.pipeline.stages import StageSpec, validate_chain
-from dmlc_tpu.pipeline.stats import StageProbe, snapshot
+from dmlc_tpu.pipeline.stats import StageProbe, _item_stats, snapshot
 from dmlc_tpu.utils.logging import DMLCError, check
 
 __all__ = ["Pipeline", "CompiledPipeline"]
@@ -61,31 +61,31 @@ def _probed(runner) -> Iterator:
     pair the probe accumulates into ``wait_s`` — so per-stage span
     totals and probe waits agree by construction (the terminal
     end-of-stream wait goes to ``pull/<stage>.end`` to keep the
-    span-count == items invariant exact). Each pull also registers
-    with the stall watchdog while it blocks."""
+    span-count == items invariant exact). While a jax.profiler session
+    collects, the same pull is a live span on the profiler's timeline
+    (its terminal pull tagged ``end=1``, as its name is known only once
+    it returns). Each pull also registers with the stall watchdog while
+    it blocks."""
     gen = runner.epoch()
     probe = runner.probe
     pull_name = f"pull/{probe.name}"      # loop-invariant: built once,
     end_name = pull_name + ".end"         # not per delivered item
     while True:
-        rec = _trace.active()
         token = _watchdog.begin_wait(pull_name, runner.wait_detail)
-        t0 = time.perf_counter()
+        sp = _trace.begin(pull_name, "pipeline")
+        item = _END
         try:
             item = next(gen, _END)
         finally:
             # a raising stage must not leave a phantom wait registered
             # — the watchdog would later report a stall that never was
             _watchdog.end_wait(token)
-        dt = time.perf_counter() - t0
+            # a pull that raised ends the stream like the terminal one
+            dt = sp.end(end_name, end=1) if item is _END else sp.end()
         if item is _END:
             probe.record_wait_only(dt)
-            if rec is not None:
-                rec.complete(end_name, t0, dt, "pipeline")
             return
-        probe.record(item, dt, runner.queue)
-        if rec is not None:
-            rec.complete(pull_name, t0, dt, "pipeline")
+        probe.record(runner.item_stats(item), dt, runner.queue)
         yield item
 
 
@@ -123,6 +123,11 @@ class _RunnerBase:
 
     def epoch(self) -> Iterator:
         raise NotImplementedError
+
+    def item_stats(self, item) -> tuple:
+        """(rows, nnz, bytes) of the item this stage just delivered,
+        from host values only."""
+        return _item_stats(item)
 
     def detach_last(self):
         """Take ownership of the last yielded item's arena lease
@@ -492,6 +497,12 @@ class _ShardRunner(_RunnerBase):
     def epoch(self) -> Iterator:
         return iter(self._it)
 
+    def item_stats(self, item) -> tuple:
+        # the round's host counts, taken before its put: the global
+        # batch's own count arrays live on the devices
+        rows, nnz = self._it.last_counts
+        return rows, nnz, sum(int(v.nbytes) for v in item.values())
+
     @property
     def queue(self):
         # the live serve ThreadedIter while an epoch runs: occupancy
@@ -520,6 +531,11 @@ class _ShardRunner(_RunnerBase):
         serve = getattr(it, "_serve_stats", None)
         if serve:
             self.probe.extra["serve"] = dict(serve)
+        # the same fields the single-stream assemble stage reports
+        engine = it.engine_stats()
+        if engine is not None:
+            self.probe.extra["engine"] = engine
+        self.probe.extra["assemble_s"] = round(it.stack_s, 6)
 
     def close(self) -> None:
         close = getattr(self._it, "close", None)
@@ -812,6 +828,9 @@ class _DeviceRunner(_RunnerBase):
         check(staging in (True, False, "auto"),
               "to_device(staging) must be True, False or 'auto'")
         self._staging = staging
+        # host (rows, nnz, bytes) of the batch last delivered, counted
+        # before its put: the delivered arrays live on the device
+        self._delivered = (0, 0, 0)
 
     @staticmethod
     def _host_arrays(item) -> Dict[str, np.ndarray]:
@@ -824,6 +843,9 @@ class _DeviceRunner(_RunnerBase):
             if v is not None:
                 out[k] = v
         return out
+
+    def item_stats(self, item) -> tuple:
+        return self._delivered
 
     def _platform(self) -> str:
         import jax
@@ -856,27 +878,26 @@ class _DeviceRunner(_RunnerBase):
 
         def drain_one():
             nonlocal xfer_wait
-            fut, lease, slot, t_enq = in_flight.popleft()
-            t0 = time.perf_counter()
-            jax.block_until_ready(fut)
-            now = time.perf_counter()
-            dt = now - t0
+            fut, lease, slot, t_enq, counts = in_flight.popleft()
+            sp = _trace.begin("to_device.drain", "transfer")
+            try:
+                jax.block_until_ready(fut)
+            finally:
+                dt = sp.end(in_flight=len(in_flight) + 1)
             xfer_wait += dt
             self.probe.extra["xfer_wait_s"] = round(xfer_wait, 6)
-            rec = _trace.active()
-            if rec is not None:
-                rec.complete("to_device.drain", t0, dt, "transfer",
-                             {"in_flight": len(in_flight) + 1})
-                if slot is not None:
-                    # the full async window, enqueue → ready: it
-                    # overlaps the NEXT batch's device.assemble span —
-                    # the Perfetto-visible proof the double-buffer works
-                    rec.complete("device.xfer", t_enq, now - t_enq,
-                                 "transfer")
+            if sp.rec is not None and slot is not None:
+                # the full async window, enqueue → ready: it overlaps
+                # the NEXT batch's to_device.stage span — the
+                # Perfetto-visible proof the double-buffer works
+                # (recorder only: it overlaps other spans)
+                sp.rec.complete("device.xfer", t_enq, sp.t0 + dt - t_enq,
+                                "transfer")
             if lease is not None:
                 lease.release()
             if slot is not None:
                 pool.release(slot)
+            self._delivered = counts
             return fut
 
         for item in _probed(self.up):
@@ -891,6 +912,7 @@ class _DeviceRunner(_RunnerBase):
             else:
                 lease = self.up.detach_last()
             arrs = self._host_arrays(item)
+            counts = _item_stats(arrs)
             if pool is None and self._staging == "auto" \
                     and isinstance(item, dict):
                 pool = HostStaging(self.window + 1,
@@ -916,8 +938,12 @@ class _DeviceRunner(_RunnerBase):
                 arrs = {k: np.array(v, copy=True) for k, v in arrs.items()}
                 lease.release()
                 lease = None
-            fut = put(arrs)
-            in_flight.append((fut, lease, slot, time.perf_counter()))
+            sp = _trace.begin("to_device.put", "transfer")
+            try:
+                fut = put(arrs)
+            finally:
+                t_enq = sp.t0 + sp.end()
+            in_flight.append((fut, lease, slot, t_enq, counts))
             # window is re-read each round: the autotuner adjusts it
             # between epochs (and a mid-epoch change is simply honored)
             while len(in_flight) > self.window:
@@ -970,40 +996,62 @@ class CompiledPipeline:
     def __iter__(self) -> Iterator:
         """One epoch. At a COMPLETE epoch the stats snapshot is frozen
         and the autotuner (if bound) takes its between-epoch step; an
-        abandoned epoch leaves the previous snapshot in place."""
+        abandoned epoch leaves the previous snapshot in place.
+
+        Two spans bracket it: ``pipeline.epoch_start``, from entering
+        the epoch to just before its first item is yielded (runner
+        resets, restarts, the fill of a transfer window: the first
+        pull nests inside it), and ``pipeline.epoch_end``, the
+        finalize, snapshot and tuning hook after the last pull."""
+        start = _trace.begin("pipeline.epoch_start", "pipeline")
+        first = True
         for r in self._runners:
             r.probe.reset()
         sched = None
         if self.tenant is not None:
             from dmlc_tpu.pipeline import scheduler as _sched
             sched = _sched.active()
+        last = self._runners[-1]
         t0 = time.perf_counter()
-        if sched is None:
-            yield from _probed(self._runners[-1])
-        else:
-            # multi-tenant discipline: every delivered batch costs one
-            # pull credit FIRST (a credit-blocked tenant stops pulling
-            # — its bounded queues fill and the throttle propagates up
-            # to its readers), then bills its latency + volume to the
-            # tenant's accounting. The billed latency is the
-            # tenant-EXPERIENCED wait — credit wait included — because
-            # that is what a declared latency SLO (obs.slo) judges: a
-            # credit-starved tenant is missing its objective even when
-            # its pipeline produces instantly
-            from dmlc_tpu.pipeline.stats import _item_stats
-            gen = _probed(self._runners[-1])
+        gen = _probed(last)
+        try:
             while True:
-                tb = time.perf_counter()
-                sched.acquire(self.tenant)
+                # multi-tenant discipline: every delivered batch costs one
+                # pull credit FIRST (a credit-blocked tenant stops pulling
+                # — its bounded queues fill and the throttle propagates up
+                # to its readers), then bills its latency + volume to the
+                # tenant's accounting. The billed latency is the
+                # tenant-EXPERIENCED wait — credit wait included — because
+                # that is what a declared latency SLO (obs.slo) judges: a
+                # credit-starved tenant is missing its objective even when
+                # its pipeline produces instantly
+                if sched is not None:
+                    tb = time.perf_counter()
+                    sched.acquire(self.tenant)
                 item = next(gen, _END)
+                if first:
+                    first = False
+                    start.end()
                 if item is _END:
                     break
-                rows, _nnz, nbytes = _item_stats(item)
-                sched.note_batch(self.tenant,
-                                 time.perf_counter() - tb,
-                                 rows=rows, nbytes=nbytes)
+                if sched is not None:
+                    rows, _nnz, nbytes = last.item_stats(item)
+                    sched.note_batch(self.tenant,
+                                     time.perf_counter() - tb,
+                                     rows=rows, nbytes=nbytes)
                 yield item
+        finally:
+            if first:  # the first pull raised
+                start.end()
+            gen.close()  # an abandoned epoch releases its stages now
         wall = time.perf_counter() - t0
+        end = _trace.begin("pipeline.epoch_end", "pipeline")
+        try:
+            self._end_epoch(wall, sched)
+        finally:
+            end.end()
+
+    def _end_epoch(self, wall: float, sched) -> None:
         for r in self._runners:
             r.finalize_epoch()
         self._epoch += 1
@@ -1308,7 +1356,7 @@ class Pipeline:
         window="auto" is an autotuner knob. ``staging`` routes batches
         through a reusable host staging pair (copy frees the source
         immediately; transfer N overlaps assembly N+1, proven by
-        device.assemble/device.xfer spans and the device.staging
+        to_device.stage/device.xfer spans and the device.staging
         gauge): True, False, or "auto" (on for dict batches — the
         fixed-shape padded steady path — off for RowBlock streams)."""
         return self._with(StageSpec("to_device", device=device,

@@ -40,9 +40,20 @@ __all__ = ["StageProbe", "snapshot", "PIPELINE_STATS_SCHEMA"]
 PIPELINE_STATS_SCHEMA = 1
 
 
+def _host_count(v) -> int:
+    """Sum of a host count field; 0 for a device array, which is never
+    read back here (telemetry must not wait on the device)."""
+    if isinstance(v, (np.ndarray, np.generic)):
+        return int(v.sum())
+    return v if isinstance(v, int) else 0
+
+
 def _item_stats(item) -> tuple:
-    """(rows, nnz, bytes) of one pipeline item: RowBlock, array dict,
-    or opaque (counted as zeros — items is always exact)."""
+    """(rows, nnz, bytes) of one host pipeline item: RowBlock, array
+    dict, or opaque (counted as zeros — items is always exact). Count
+    fields held on a device are not read (they count as zeros): the
+    stages that deliver device arrays report the host counts they had
+    before the transfer instead (``_RunnerBase.item_stats``)."""
     # RowBlock duck-type: .offset/.size/.memory_cost_bytes
     cost = getattr(item, "memory_cost_bytes", None)
     if cost is not None:
@@ -56,13 +67,13 @@ def _item_stats(item) -> tuple:
                 nbytes += int(nb)
         nr = item.get("num_rows")
         if nr is not None:
-            rows = int(np.sum(np.asarray(nr)))
+            rows = _host_count(nr)
         elif "label" in item and hasattr(item["label"], "shape"):
             shape = item["label"].shape
             rows = int(np.prod(shape)) if shape else 0
         nz = item.get("num_nnz")
         if nz is not None:
-            nnz = int(np.sum(np.asarray(nz)))
+            nnz = _host_count(nz)
         elif "index" in item and hasattr(item["index"], "shape"):
             nnz = int(np.prod(item["index"].shape))
         return rows, nnz, nbytes
@@ -93,11 +104,12 @@ class StageProbe:
         self.queue_cap: Optional[int] = None
         self.extra = {}
 
-    def record(self, item, wait_s: float, queue=None) -> None:
-        """One delivered item: wait seconds + volume + queue sample."""
+    def record(self, counts: tuple, wait_s: float, queue=None) -> None:
+        """One delivered item: wait seconds + its (rows, nnz, bytes) +
+        queue sample."""
         self.wait_s += wait_s
         self.items += 1
-        rows, nnz, nbytes = _item_stats(item)
+        rows, nnz, nbytes = counts
         self.rows += rows
         self.nnz += nnz
         self.bytes += nbytes

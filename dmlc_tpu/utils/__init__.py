@@ -17,9 +17,6 @@ from dmlc_tpu.utils.thread_group import (
     ManualEvent, ThreadGroup, ThreadLocalStore,
 )
 from dmlc_tpu.utils.memory import BufferPool, thread_local_pool
-# canonical home since the obs/ subsystem; utils.profiler is a
-# deprecation shim over these same objects
-from dmlc_tpu.obs.trace import Profiler, profiler
 
 __all__ = [
     "DMLCError", "check", "check_eq", "check_ne", "check_lt", "check_le",
@@ -28,5 +25,4 @@ __all__ = [
     "field", "get_env", "ParamError", "Config", "get_time",
     "ConcurrentBlockingQueue", "PriorityBlockingQueue", "ManualEvent",
     "ThreadGroup", "ThreadLocalStore", "BufferPool", "thread_local_pool",
-    "Profiler", "profiler",
 ]

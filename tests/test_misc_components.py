@@ -17,7 +17,6 @@ from dmlc_tpu.parallel.backends import (
 )
 from dmlc_tpu.utils.logging import DMLCError
 from dmlc_tpu.utils.memory import BufferPool, thread_local_pool
-from dmlc_tpu.obs.trace import Profiler
 from dmlc_tpu.utils.thread_group import ManualEvent, ThreadGroup
 
 
@@ -111,26 +110,6 @@ class TestBenchTransferProbe:
         assert 0.0 <= share <= threading.active_count() + 2, share
         rate, copied = bt.cell_under_cpu_load(dev, 1, 1, 2)
         assert rate > 0 and copied >= 0
-
-
-class TestProfiler:
-    def test_stage_accumulation(self):
-        p = Profiler()
-        with p.stage("parse", nbytes=1000, items=10):
-            time.sleep(0.01)
-        with p.stage("parse", nbytes=500, items=5):
-            pass
-        st = p.stats()["parse"]
-        assert st.calls == 2 and st.bytes == 1500 and st.items == 15
-        assert st.seconds >= 0.01
-        assert "parse" in p.report()
-
-    def test_disabled(self):
-        p = Profiler()
-        p.enabled = False
-        with p.stage("x"):
-            pass
-        assert p.stats() == {}
 
 
 class TestIndexedRecordIOWriter:

@@ -4,7 +4,7 @@ cases (empty rows, short last batch, mid-file schema flip,
 release-after-EOF), padded-lease leak discipline (source arenas return
 to the free list the moment a batch is cut), sharded single-file parse
 byte-identity, and the double-buffered staging overlap proof
-(device.xfer spans intersecting the next batch's device.assemble)."""
+(device.xfer spans intersecting the next batch's to_device.stage)."""
 
 import numpy as np
 import pytest
@@ -383,19 +383,19 @@ class TestStagingOverlap:
         finally:
             obs_trace.stop()
         assert len(out) == len(batches)
-        spans = {"device.xfer": [], "device.assemble": []}
+        spans = {"device.xfer": [], "to_device.stage": []}
         for ph, name, _cat, t, d, _tid, _args in rec.events():
             if ph == "X" and name in spans:
                 spans[name].append((t, t + d))
         assert len(spans["device.xfer"]) == len(batches)
-        assert len(spans["device.assemble"]) == len(batches)
+        assert len(spans["to_device.stage"]) == len(batches)
         # non-empty intersection with an assemble that STARTED inside
         # the transfer's enqueue→ready window: the overlap is real, not
         # a pair of adjacent spans
         overlapping = [
             (x, a)
             for x in spans["device.xfer"]
-            for a in spans["device.assemble"]
+            for a in spans["to_device.stage"]
             if x[0] < a[0] < x[1]
         ]
         assert overlapping, \

@@ -295,20 +295,28 @@ class TestMetricsRegistry:
         assert reg.snapshot()["collectors"][name] is None
 
     def test_existing_surfaces_register(self):
-        """The five pre-obs stats() surfaces land in one snapshot: a
-        named ThreadedIter and the global profiler here (native engine
-        + pipeline covered by their own suites)."""
+        """The pre-obs stats() surfaces land in one snapshot: a named
+        ThreadedIter and a thread's BufferPool here (native engine +
+        pipeline covered by their own suites)."""
+        from dmlc_tpu.utils.memory import thread_local_pool
         ti = ThreadedIter(max_capacity=2, name="reg.demo")
         src = iter([1, 2])
         ti.init(lambda: next(src, None))
         while ti.next() is not None:
             pass
+        pools = []  # held: collectors are registered weakly
+        t = threading.Thread(target=lambda: pools.append(thread_local_pool()),
+                             name="reg.pool")
+        t.start()
+        t.join(timeout=5)
+        assert not t.is_alive() and pools
         snap = REGISTRY.snapshot()
         keys = [k for k in snap["collectors"] if k.startswith("queue/reg.demo")]
         assert keys, snap["collectors"].keys()
         got = snap["collectors"][keys[0]]
         assert got["produced"] == 2 and "capacity" in got
-        assert "profiler" in snap["collectors"]
+        assert snap["collectors"]["buffer_pool/reg.pool"] == {
+            "allocated": 0, "reused": 0}
         ti.destroy()
         assert not [k for k in REGISTRY.snapshot()["collectors"]
                     if k.startswith("queue/reg.demo")]
@@ -521,25 +529,3 @@ class TestGangTracing:
     def test_merge_gang_traces_empty_dir(self, tmp_path):
         from dmlc_tpu.parallel.launch import merge_gang_traces
         assert merge_gang_traces(str(tmp_path)) is None
-
-
-class TestProfilerShim:
-    def test_deprecated_import_warns_and_aliases(self):
-        import dmlc_tpu.utils.profiler as shim
-        with pytest.warns(DeprecationWarning):
-            cls = shim.Profiler
-        assert cls is obs_trace.Profiler
-        with pytest.warns(DeprecationWarning):
-            assert shim.trace is obs_trace.jax_trace
-
-    def test_profiler_stage_feeds_recorder(self):
-        rec = obs_trace.start()
-        p = obs_trace.Profiler()
-        with p.stage("fold", nbytes=100, items=2):
-            pass
-        obs_trace.stop()
-        st = p.stats()["fold"]
-        assert st.calls == 1 and st.bytes == 100
-        spans = [e for e in rec.events() if e[0] == "X"
-                 and e[1] == "fold"]
-        assert len(spans) == 1  # one span API: stage() == span
