@@ -9,7 +9,8 @@ bucket compiles once.
 
 Representations:
 - flat CSR: (offset[n+1], index[nnz], value[nnz]) — SpMV via segment-sum
-  (row ids recovered with searchsorted; fully jittable, no dynamic shapes).
+  (row ids from an O(nnz) prefix count over the offsets; fully jittable,
+  no dynamic shapes, no serial loop).
 - padded ELL: (index[n, k], value[n, k]) with zero-padded tails — the
   MXU-friendly layout for dense-ish downstream math.
 """
@@ -37,9 +38,15 @@ def csr_row_ids(offset: jnp.ndarray, nnz: int) -> jnp.ndarray:
 
     Padded tail entries (k >= offset[-1]) map to row n (one-past-last) so
     segment ops can drop them via num_segments=n.
+
+    Counted, not searched: row_ids[k] = #{i : offset[i] <= k} - 1, so one
+    scatter of the n+1 offsets into nnz bins (repeated offsets of empty
+    rows share a bin; offsets >= nnz are dropped, they are never <= k) and
+    one prefix sum. O(n + nnz) with no serial loop, where searchsorted
+    lowers to a binary-search while loop over all nnz positions.
     """
-    return jnp.searchsorted(offset, jnp.arange(nnz, dtype=offset.dtype),
-                            side="right") - 1
+    hits = jnp.zeros((nnz,), jnp.int32).at[offset].add(1, mode="drop")
+    return jnp.cumsum(hits) - 1
 
 
 @partial(jax.jit, static_argnames=("num_rows",))

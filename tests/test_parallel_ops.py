@@ -14,8 +14,8 @@ from dmlc_tpu.data.parser import Parser
 from dmlc_tpu.data.rowblock import RowBlock, RowBlockContainer
 from dmlc_tpu.models import SparseFMModel, SparseLinearModel
 from dmlc_tpu.ops import (
-    csr_to_dense, csr_to_padded_rows, sdot_rows, segment_spmv, sharded_spmv,
-    spmv,
+    csr_row_ids, csr_to_dense, csr_to_padded_rows, sdot_rows, segment_spmv,
+    sharded_spmv, spmv,
 )
 from dmlc_tpu.parallel import (
     DeviceIter, ShardedRowBlockIter, device_prefetch, empty_block,
@@ -39,7 +39,61 @@ def random_block(rng, rows=64, ncol=50, max_nnz=8):
     return c.get_block()
 
 
+def _offsets(lengths, nnz=None):
+    """CSR offsets for rows of the given lengths; nnz defaults to the
+    total (no padded tail)."""
+    offset = np.concatenate([[0], np.cumsum(lengths)])
+    return offset, int(offset[-1] if nnz is None else nnz)
+
+
+ROW_ID_CASES = {
+    # rows of 0..7 nonzeros: empty rows repeat an offset
+    "empty_rows": lambda rng: _offsets(rng.randint(0, 8, size=64), 512),
+    "padded_tail": lambda rng: _offsets([3, 0, 5, 2], 64),
+    "nnz_equals_last_offset": lambda rng: _offsets([4, 1, 0, 6, 2]),
+    "one_row": lambda rng: _offsets([9], 16),
+    "all_padded": lambda rng: _offsets(np.zeros(32, np.int64), 128),
+    "more_rows_than_nnz": lambda rng: _offsets(
+        (rng.rand(200) < 0.05).astype(np.int64), 16),
+    # nnz shorter than offset[-1]: offsets past nnz are never <= k
+    "offsets_past_nnz": lambda rng: _offsets([5, 5, 5], 8),
+}
+
+
 class TestCsrOps:
+    @pytest.mark.parametrize("x64", [False, True], ids=["int32", "int64"])
+    @pytest.mark.parametrize("case", sorted(ROW_ID_CASES))
+    def test_csr_row_ids_matches_searchsorted(self, rng, case, x64):
+        offset, nnz = ROW_ID_CASES[case](rng)
+        gold = np.searchsorted(offset, np.arange(nnz), side="right") - 1
+        dtype = np.int64 if x64 else np.int32
+        with jax.enable_x64(x64):
+            off = jnp.asarray(offset.astype(dtype))
+            assert off.dtype == dtype
+            eager = csr_row_ids(off, nnz)
+            jitted = jax.jit(csr_row_ids, static_argnums=1)(off, nnz)
+        assert jnp.issubdtype(eager.dtype, jnp.integer)
+        np.testing.assert_array_equal(np.asarray(eager), gold)
+        np.testing.assert_array_equal(np.asarray(jitted), gold)
+
+    def test_row_ids_lower_without_a_loop(self):
+        """A serial search (searchsorted lowers to a while loop) must not
+        come back into the step: lowering only, no device needed."""
+        rows, nnz, ncol = 16, 128, 50
+        batch = {"offset": jnp.zeros(rows + 1, jnp.int32),
+                 "label": jnp.zeros(rows, jnp.float32),
+                 "weight": jnp.zeros(rows, jnp.float32),
+                 "index": jnp.zeros(nnz, jnp.uint32),
+                 "value": jnp.zeros(nnz, jnp.float32)}
+        spmv_ir = segment_spmv.lower(
+            batch["offset"], batch["index"], batch["value"],
+            jnp.zeros(ncol, jnp.float32), num_rows=rows).as_text()
+        model = SparseLinearModel(ncol)
+        step_ir = SparseLinearModel.train_step.lower(
+            model, model.init_params(), batch).as_text()
+        for ir in (spmv_ir, step_ir):
+            assert "stablehlo.while" not in ir
+
     def test_spmv_matches_numpy(self, rng):
         block = random_block(rng)
         w = rng.rand(50).astype(np.float32)

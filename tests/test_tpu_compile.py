@@ -73,6 +73,8 @@ def test_train_step_one_chip(topo):
     mem = compiled.memory_analysis()
     # params in and out, one batch, scratch: far inside 16 GB of HBM
     assert mem.argument_size_in_bytes < 64 << 20
+    # row ids are counted, not searched: no serial loop in the step
+    assert " while(" not in compiled.as_text()
 
 
 def test_sharded_spmv(mesh):
