@@ -3,7 +3,11 @@ the benchmark's own run (``drive.run``) with the timed path broken
 underneath. The benchmark's own runs never run this.
 
     python3 benchmark/control.py --workload <cell> --seeds 11,12,13 \
-        [--plants prog,control] [--seconds 2]
+        [--plants prog,control] [--seconds 2] [--root DIR --cache DIR]
+
+``--root`` and ``--cache`` take the cell from another checkout's
+``BENCHMARK.json`` and files, such as one that
+``tests/benchmark/bench_helpers.py`` writes with cells of its own.
 
 Plants, each put in at the program's boundary:
 
@@ -18,9 +22,17 @@ Plants, each put in at the program's boundary:
 - ``unchanged``: a step that returns its state unchanged.
 - ``exchange``: on a mesh, the sums between chips not exchanged (chip
   0's own stand for all of them).
+- ``unshuffled``: the shuffle step dropped (``Pipeline.shuffle`` returns
+  the pipeline as it was), while the traffic still says its rows arrive
+  out of source order.
+- ``repeated``: each batch's second row replaced by its first where the
+  batch is yielded.
+- ``twice``: under a chunk-level shuffle, each epoch reads its first
+  sub-shard again in place of its last (``InputSplitShuffle``).
 
-Prints one JSON line per seed and plant (``correct`` and each number
-compared), then, per plant, the largest and smallest of each number.
+Prints one JSON line per seed and plant (``correct``, each number
+compared, and the run's epochs, engine, assembly path and locator
+seconds), then, per plant, the largest and smallest of each number.
 """
 
 import argparse
@@ -62,20 +74,66 @@ def _ref_step(lr, params, batch):
     return {"w": w.astype(np.float32), "b": np.float32(b)}, np.float32(loss)
 
 
+def _repeat_first(b):
+    """``b`` with each shard's second row replaced by its first, on the
+    devices it came from."""
+    import jax
+    import numpy as np
+    h = {k: np.array(v) for k, v in jax.device_get(b).items()}
+    mesh = np.ndim(h["num_rows"]) > 0
+    for at in [(d,) for d in range(len(h["num_rows"]))] if mesh else [()]:
+        n, off = int(h["num_rows"][at]), h["offset"][at].astype(np.int64)
+        if n < 2:
+            continue
+        take = np.concatenate([np.arange(off[0], off[1]), np.arange(off[0], off[1]),
+                               np.arange(off[2], off[n])])
+        for k in ("index", "value"):
+            a = h[k][at]
+            a[off[0]:off[0] + len(take)], a[off[0] + len(take):off[n]] = a[take], 0
+        length = np.diff(off[:n + 1])
+        length[1] = length[0]
+        o = h["offset"][at]
+        o[1:n + 1] = off[0] + np.cumsum(length)
+        o[n + 1:] = o[n]
+        h["num_nnz"][at] = o[n] - off[0]
+        for k in ("label", "weight"):
+            h[k][at][1] = h[k][at][0]
+    return {k: jax.device_put(h[k], v.sharding) if hasattr(v, "sharding") else h[k]
+            for k, v in b.items()}
+
+
+def _read_first_twice(before_first):
+    """``InputSplitShuffle.before_first`` that, after the epoch's order
+    is drawn, puts a split of the first sub-shard read in the place of
+    the last one read."""
+    from dmlc_tpu.io.input_split import InputSplit
+
+    def f(self):
+        self._subs = list(self.__dict__.setdefault("_own_subs", self._subs))
+        before_first(self)
+        n = self._num_shuffle_parts
+        self._subs[self._order[-1]] = InputSplit.create(
+            self._uri, self.part_index * n + int(self._order[0]), self.num_parts * n,
+            self._split_type, **self._kwargs)
+    return f
+
+
 @contextlib.contextmanager
 def planted(plant):
     """The program with ``plant`` put in (see the module's doc); None or
     ``prog`` leaves it sound."""
     import jax
     import jax.numpy as jnp
+    from dmlc_tpu.io.input_split_shuffle import InputSplitShuffle
     from dmlc_tpu.models import common
     from dmlc_tpu.models.common import SparseModelBase
-    from dmlc_tpu.pipeline import CompiledPipeline
+    from dmlc_tpu.pipeline import CompiledPipeline, Pipeline
 
     tamper = {
         "control": lambda b: dict(b, value=b["value"].astype(jnp.bfloat16).astype(jnp.float32)),
         "half": lambda b: dict(b, weight=b["weight"].at[..., b["weight"].shape[-1] // 2:].set(0)),
         "altered": lambda b: dict(b, value=b["value"].at[..., 0].add(1)),
+        "repeated": _repeat_first,
     }.get(plant)
     sharded = SparseModelBase.make_sharded_train_step
     step = None
@@ -105,6 +163,11 @@ def planted(plant):
         if step is not None:
             put(SparseModelBase, "train_step", step)
             put(SparseModelBase, "make_sharded_train_step", step_on_mesh)
+        if plant == "unshuffled":
+            put(Pipeline, "shuffle", lambda self, *a, **kw: self)
+        if plant == "twice":
+            put(InputSplitShuffle, "before_first",
+                _read_first_twice(InputSplitShuffle.before_first))
         if plant == "exchange":
             def chip0(x, axis):
                 return jax.lax.psum(jnp.where(jax.lax.axis_index(axis) == 0, x, 0), axis)
@@ -112,14 +175,20 @@ def planted(plant):
         yield
 
 
+def _run(name: str, seed: int, plant, seconds: float, **kw) -> dict:
+    from benchmark import drive
+    with planted(plant):
+        return drive.run(name, seed, seconds, False, time.perf_counter(), **kw)
+
+
+def _numbers(out: dict) -> dict:
+    return {"correct": out["correct"], **{k: c["value"] for k, c in out["checks"].items()}}
+
+
 def reading(name: str, seed: int, plant=None, seconds: float = 2.0, **kw) -> dict:
     """One run of cell ``name`` through ``drive.run`` with ``plant`` put
     in: ``correct`` and each number compared."""
-    from benchmark import drive
-    with planted(plant):
-        out = drive.run(name, seed, seconds, False, time.perf_counter(), **kw)
-    return {"correct": out["correct"],
-            **{k: c["value"] for k, c in out["checks"].items()}}
+    return _numbers(_run(name, seed, plant, seconds, **kw))
 
 
 def main(argv=None) -> int:
@@ -128,7 +197,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--plants", default="prog,control")
     ap.add_argument("--seconds", type=float, default=2.0)
+    ap.add_argument("--root")
+    ap.add_argument("--cache")
     args = ap.parse_args(argv)
+    kw = {k: getattr(args, k) for k in ("root", "cache") if getattr(args, k)}
     from benchmark import drive
     plants = args.plants.split(",")
     rows = []
@@ -136,8 +208,12 @@ def main(argv=None) -> int:
         for s in args.seeds.split(","):
             for p in plants:
                 t = time.perf_counter()
-                r = reading(args.workload, int(s), p, args.seconds)
-                rows.append(dict(r, seed=int(s), plant=p, s=time.perf_counter() - t))
+                out = _run(args.workload, int(s), p, args.seconds, **kw)
+                info = {k: out["info"][k] for k in ("epochs_done", "batches", "engine",
+                                                    "assembly_path", "locator_s")
+                        if k in out["info"]}
+                rows.append(dict(_numbers(out), seed=int(s), plant=p,
+                                 s=time.perf_counter() - t, info=info))
                 print(json.dumps(rows[-1]), flush=True)
     except drive.NoDevice as e:
         print(f"control: {e}", file=sys.stderr)
@@ -146,7 +222,7 @@ def main(argv=None) -> int:
     for p in plants:
         mine = [r for r in rows if r["plant"] == p]
         summary[p] = {"runs": len(mine), "correct": sum(r["correct"] for r in mine)}
-        for k in sorted({k for r in mine for k in r} - {"correct", "seed", "plant", "s"}):
+        for k in sorted({k for r in mine for k in r} - {"correct", "seed", "plant", "s", "info"}):
             vals = [r[k] for r in mine if k in r]
             summary[p][k] = {"min": min(vals), "max": max(vals)}
     print(json.dumps({"workload": args.workload, "summary": summary}), flush=True)

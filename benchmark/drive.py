@@ -14,6 +14,14 @@ reference's parse of the same text, every finished epoch must have
 delivered every row once, and the consumer's first steps are compared
 with the reference's replay. The reference runs after the window, with
 the program's device state freed.
+
+A traffic that says its rows arrive out of source order (``"rows":
+"out_of_order"``, with steps before the parse such as a shuffle) has
+each checked row found in the corpus by its content (``locate``), and
+two more checks: no content delivered in an epoch more often than the
+rows of the part that hold it, over every row the window delivers (a
+key of each row's content is taken on the device as it is fed), and no
+checked window batch exactly where source order would put it.
 """
 
 from __future__ import annotations
@@ -23,17 +31,19 @@ import json
 import os
 import shutil
 import time
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from benchmark import ref
-from benchmark.gen import CACHE, ensure_corpus, generator
+from benchmark import locate, ref
+from benchmark.gen import CACHE, Stream, ensure_corpus, generator
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 # window batches drawn from the seed for the row-by-row comparison
 SAMPLED_BATCHES = 4
+# a step argument that takes the run's --seed
+RUN_SEED = "<run seed>"
 
 
 class NoDevice(RuntimeError):
@@ -83,17 +93,31 @@ def persists(traffic: dict) -> bool:
     return any("path" in kw for _, kw in traffic.get("steps", []))
 
 
-def build_pipeline(cfg: dict, traffic: dict, uri: str, devs, cache: str):
+def out_of_order(traffic: dict) -> bool:
+    """Whether the traffic's rows arrive out of source order."""
+    return traffic.get("rows") == "out_of_order"
+
+
+def build_pipeline(cfg: dict, traffic: dict, uri: str, devs, cache: str,
+                   seed: int):
     """The program's pipeline for this cell, through its user entry
-    points, setting only the sizes the configuration fixes: the native
-    parse, the steps the traffic names (``[method, arguments]`` each; a
-    ``path`` is a file name under the cache, per configuration and
-    generator version), then padded batches to the chip, or the shard
-    onto a mesh of the cell's chips."""
+    points, setting only the sizes the configuration fixes: the steps
+    the traffic names before the parse (``before_parse``; an argument
+    given as ``"<run seed>"`` takes ``seed``), the parse (the native
+    engine where nothing comes before it, else the program's own
+    choice), the steps it names after (``steps``; ``[method,
+    arguments]`` each; a ``path`` is a file name under the cache, per
+    configuration and generator version), then padded batches to the
+    chip, or the shard onto a mesh of the cell's chips."""
     from dmlc_tpu.pipeline import Pipeline
     rows = cfg["batch_rows"]
     nnz = rows * cfg["nnz_per_row"]
-    pipe = Pipeline.from_uri(uri).parse(engine="native", **cfg["parse"])
+    pipe = Pipeline.from_uri(uri)
+    before = traffic.get("before_parse", [])
+    for step, kw in before:
+        pipe = getattr(pipe, step)(**{k: seed if v == RUN_SEED else v
+                                      for k, v in kw.items()})
+    pipe = pipe.parse(**cfg["parse"]) if before else pipe.parse(engine="native", **cfg["parse"])
     for step, kw in traffic.get("steps", []):
         if "path" in kw:
             kw = dict(kw, path=os.path.join(
@@ -124,6 +148,65 @@ def _readers(per_layer: List[dict]):
     return out
 
 
+def served_by(stats: Optional[dict]) -> Dict[str, Optional[str]]:
+    """The parse engine and the assembly path that served the newest
+    finished epoch, as its stats snapshot names them: the native engine
+    leaves its counters under a stage's ``engine``, the Python golden
+    none; a page replay parses nothing (``engine`` None)."""
+    stages = (stats or {}).get("stages", [])
+    extra = [st.get("extra") or {} for st in stages]
+    kinds = [st["kind"] for st in stages]
+    engine = ("native" if any("engine" in x for x in extra)
+              else "python" if "parse" in kinds else None)
+    path = next((x["assembly_path"] for x in extra if "assembly_path" in x),
+                "shard" if "shard" in kinds else None)
+    out = {"engine": engine, "assembly_path": path}
+    out.update({"replay_tier": x["replay_tier"] for x in extra if "replay_tier" in x})
+    return out
+
+
+def locate_rows(loc: "locate.Locator", stream: Stream, first: np.ndarray,
+                host_samples: Dict[int, Any], epochs: np.ndarray, lo: np.ndarray):
+    """The source row of each row of each checked batch, by content,
+    within its chip's byte-range part. Rows with one content are a
+    multiset: within an epoch, each delivery takes the next such source
+    row. A row whose content no source row of the part holds takes the
+    row that source order puts in its place (``lo``, the first row of
+    each batch on each chip in source order), which the exact
+    comparison then counts as mismatched."""
+    used: Dict[tuple, int] = {}
+    found: Dict[tuple, np.ndarray] = {}
+    for i in sorted(host_samples):
+        for d, shard in enumerate(ref.shards(host_samples[i])):
+            keys = locate.row_keys(ref.device_rows(shard))
+            out = lo[i, d] + np.arange(len(keys))
+            for j, (k, c) in enumerate(zip(keys.tolist(), loc.candidates(
+                    stream, keys, int(first[d]), int(first[d + 1])))):
+                if len(c):
+                    u = used.get((epochs[i], d, k), 0)
+                    used[epochs[i], d, k] = u + 1
+                    out[j] = c[u % len(c)]
+            found[i, d] = out
+    return found
+
+
+def epoch_bytes(num_rows: np.ndarray, epochs: np.ndarray, epoch: int,
+                stream: Stream, first: np.ndarray) -> List[float]:
+    """Text bytes of each epoch's delivered rows where they arrive out
+    of source order: a finished epoch (before ``epoch``) counts its
+    parts' exact bytes, the unfinished one its rows on each chip times
+    that chip's part's mean bytes a row."""
+    part_bytes = np.diff(stream.start[first])
+    out: List[float] = []
+    for e in np.unique(epochs):
+        if e < epoch:
+            out.append(int(part_bytes.sum()))
+        else:
+            rows = num_rows[epochs == e].sum(axis=0)
+            out.append(float((rows * part_bytes / np.diff(first)).sum()))
+    return out
+
+
 def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
         root: str = ROOT, cache: str = CACHE, require_tpu: bool = True) -> Dict[str, Any]:
     """One run of cell ``name``. Returns the result line's object; the
@@ -143,9 +226,14 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
     corpus, corpus_info = ensure_corpus(cfg, cache)
     stream = corpus.stream(None if persists(traffic) else seed)
     first = stream.part_first_rows(len(devs))
-    built, mesh = build_pipeline(cfg, traffic, stream.uri, devs, cache)
+    built, mesh = build_pipeline(cfg, traffic, stream.uri, devs, cache, seed)
     feed = consumer(traffic["consumer"], cfg, devs, mesh)
     readers = _readers(spec["per_layer"]) if trace else []
+    shuffled = out_of_order(traffic)
+    # rows out of source order: each delivered row's content key (its
+    # first lane), kept on the device until the window has closed
+    keyfn = jax.jit(locate.device_keys) if shuffled else None
+    content_keys: List[Any] = []
 
     epoch = 0
     delivered: List[tuple] = []   # (epoch, num_rows on the device)
@@ -182,6 +270,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
     def take(b):
         delivered.append((epoch, b["num_rows"]))
         last[0] = b
+        if shuffled:
+            content_keys.append(keyfn(b))
         if feed.KEEP_RESIDENT:
             resident.append(b)
         with jax.profiler.TraceAnnotation("bench.train_step"):
@@ -246,6 +336,11 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
     num_rows = np.asarray(jax.device_get([n for _, n in delivered]),
                           np.int64).reshape(len(delivered), -1)
     epochs = np.asarray([e for e, _ in delivered])
+    if shuffled:
+        lanes = np.asarray(jax.device_get(content_keys))
+        lanes = lanes.reshape(len(delivered), -1, lanes.shape[-1])
+        content_keys.clear()
+    served = served_by(built.stats())
     feed.close()
     samples.clear()
     resident.clear()
@@ -253,14 +348,12 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
     it = None
     built.close()
 
-    # -- rows and bytes of every delivered batch
+    # -- rows of every delivered batch where source order puts them
     starts = np.zeros_like(num_rows)
     for e in np.unique(epochs):
         sel = np.flatnonzero(epochs == e)
         starts[sel] = np.cumsum(num_rows[sel], axis=0) - num_rows[sel]
     lo = first[:-1][None, :] + starts
-    nbytes = (stream.start[lo + num_rows] - stream.start[lo]).sum(axis=1)
-    window_bytes = int(nbytes[n_setup:].sum())
     part_rows = np.diff(first)
     done = [e for e in np.unique(epochs) if e < epoch]
     checks: Dict[str, Dict[str, Any]] = {}
@@ -269,19 +362,47 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
                   for e in done)
         checks["epoch_rows_gap"] = {"value": gap}
 
+    # -- the source row of each checked row (in source order its place;
+    # out of it, the row the locator finds by content), and the text
+    # bytes of the window's batches
+    if shuffled:
+        loc, loc_info = locate.ensure_locator(cfg, corpus)
+        found = locate_rows(loc, stream, first, host_samples, epochs, lo)
+        repeated = loc.excess(stream, first, lanes, num_rows, epochs)
+
+        def src(i: int, d: int) -> np.ndarray:
+            return found[i, d]
+
+        # less the set-up's rows, found by content
+        row_bytes = np.diff(stream.start)
+        setup_bytes = sum(int(row_bytes[src(i, d)].sum())
+                          for i in range(n_setup) for d in range(len(devs)))
+        window_bytes = sum(epoch_bytes(num_rows, epochs, epoch, stream, first)) - setup_bytes
+    else:
+        def src(i: int, d: int) -> np.ndarray:
+            return lo[i, d] + np.arange(num_rows[i, d])
+
+        nbytes = (stream.start[lo + num_rows] - stream.start[lo]).sum(axis=1)
+        window_bytes = int(nbytes[n_setup:].sum())
+
     # -- the reference: sampled batches row by row, then the consumer's
     # first steps
     parse = ref.parser(cfg["parse"]["format"])
 
     def ref_rows(i: int, d: int):
-        a = int(lo[i, d])
-        return parse(stream.lines(a, a + int(num_rows[i, d])), **cfg["parse"])
+        return parse(stream.lines_at(src(i, d)), **cfg["parse"])
 
     bad = 0
     for i, hb in host_samples.items():
         for d, shard in enumerate(ref.shards(hb)):
             bad += ref.rows_mismatched(ref.device_rows(shard), ref_rows(i, d))
     checks["rows_mismatched"] = {"value": bad}
+    if shuffled:
+        checks["rows_repeated"] = {"value": repeated}
+        checks["rows_in_source_order"] = {"value": sum(
+            all(np.array_equal(src(i, d), lo[i, d] + np.arange(num_rows[i, d]))
+                for d in range(len(devs)))
+            for i in host_samples if i >= n_setup)}
     checks.update({k: {"value": v} for k, v in feed.check(
         lambda i: ref.concat([ref_rows(i, d) for d in range(len(devs))])).items()})
     for k, c in checks.items():
@@ -326,6 +447,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
                    "corpus_generated": corpus_info["generated"],
                    "corpus_s": corpus_info["corpus_s"],
                    "corpus_bytes": corpus_info["bytes"], "corpus_rows": corpus_info["rows"],
-                   "wait_median_ms": float(np.median(waits)) * 1e3}
+                   "wait_median_ms": float(np.median(waits)) * 1e3, **served}
+    if shuffled:
+        out["info"].update(locator_built=loc_info["built"], locator_s=loc_info["locator_s"])
     out["checks"] = checks
     return out

@@ -93,15 +93,17 @@ class Corpus:
             rng = np.random.default_rng(seed % (1 << 64))
             order = [int(k) for k in rng.permutation(len(self.files))]
         return Stream([self.files[k] for k in order],
-                      [self.lengths[k] for k in order])
+                      [self.lengths[k] for k in order], order)
 
 
 class Stream:
     """Rows of the corpus in one order: the files as one byte space,
     as a ``;``-joined URI reads them."""
 
-    def __init__(self, files: List[str], lengths: List[np.ndarray]):
+    def __init__(self, files: List[str], lengths: List[np.ndarray], order: List[int]):
         self.files = files
+        # order[k]: the corpus part (canonical place) of the k-th file
+        self.order = order
         self.uri = ";".join(files)
         lens = np.concatenate(lengths).astype(np.int64)
         # start[r] = global byte offset of row r; start[-1] = total
@@ -127,6 +129,16 @@ class Stream:
                 buf = fh.read(hi - lo)
             out.extend(buf.split(b"\n")[:end - r])
             r = end
+        return out
+
+    def lines_at(self, rows: np.ndarray) -> List[bytes]:
+        """The text of each of ``rows``, in their order, one line each;
+        each run of consecutive rows is read at once."""
+        rows = np.asarray(rows, np.int64)
+        cut = np.flatnonzero(np.diff(rows) != 1) + 1
+        out: List[bytes] = []
+        for run in np.split(rows, cut) if len(rows) else []:
+            out.extend(self.lines(int(run[0]), int(run[-1]) + 1))
         return out
 
     def part_first_rows(self, nparts: int) -> np.ndarray:

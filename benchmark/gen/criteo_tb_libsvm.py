@@ -10,7 +10,7 @@ configuration's ``assumed`` entries.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Iterator, Tuple
 
 import numpy as np
 
@@ -43,14 +43,14 @@ def prepare(cfg: dict) -> dict:
             "cat_index": perm + ncount + 1 + span * np.arange(ncat)[:, None]}
 
 
-def make_part(cfg: dict, shared: dict, part: int) -> Tuple[bytes, np.ndarray]:
+def _draw(cfg: dict, shared: dict, part: int):
+    """Part ``part``'s rows as drawn, a chunk at a time: the labels, and
+    each row's 39 indices and values in field order."""
     a, ncount, ncat, span = _params(cfg)
     rows = cfg["corpus"]["rows_per_part"]
     rng = rng_for(cfg, part)
     missing = np.asarray(a["count_missing_share"])
     mu = np.log(np.asarray(a["count_median"], np.float64))
-    (ic, ik), (vc, vk) = shared["idx"], shared["val"]
-    texts, lengths = [], []
     for lo in range(0, rows, CHUNK):
         n = min(CHUNK, rows - lo)
         label = (rng.random(n) < a["click_rate"]).astype(np.uint8)
@@ -64,8 +64,16 @@ def make_part(cfg: dict, shared: dict, part: int) -> Tuple[bytes, np.ndarray]:
         idx = np.concatenate(
             [np.broadcast_to(np.arange(1, ncount + 1), (n, ncount)), cat], 1)
         val = np.concatenate([counts, np.ones((n, ncat), np.int64)], 1)
+        yield label, idx, val
+
+
+def make_part(cfg: dict, shared: dict, part: int) -> Tuple[bytes, np.ndarray]:
+    (ic, ik), (vc, vk) = shared["idx"], shared["val"]
+    texts, lengths = [], []
+    for label, idx, val in _draw(cfg, shared, part):
+        n, k = idx.shape
         width = 1 + IDX_W + 1 + VAL_W
-        tok = np.empty((n, ncount + ncat, width), np.uint8)
+        tok = np.empty((n, k, width), np.uint8)
         keep = np.ones(tok.shape, bool)
         tok[:, :, 0] = ord(" ")
         tok[:, :, 1:1 + IDX_W], keep[:, :, 1:1 + IDX_W] = ic[idx], ik[idx]
@@ -79,3 +87,12 @@ def make_part(cfg: dict, shared: dict, part: int) -> Tuple[bytes, np.ndarray]:
         texts.append(t)
         lengths.append(ln)
     return b"".join(texts), np.concatenate(lengths)
+
+
+def rows(cfg: dict, shared: dict, part: int) -> Iterator[Dict[str, np.ndarray]]:
+    """Part ``part``'s rows as the reference parses its text, a chunk at
+    a time, without the text."""
+    for label, idx, val in _draw(cfg, shared, part):
+        n, k = idx.shape
+        yield {"label": label.astype(np.float32), "length": np.full(n, k),
+               "index": idx.reshape(-1), "value": val.reshape(-1).astype(np.float32)}

@@ -10,7 +10,7 @@ text has the published widths and digits.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Iterator, Tuple
 
 import numpy as np
 
@@ -62,22 +62,40 @@ def prepare(cfg: dict):
     return out
 
 
-def make_part(cfg: dict, cols, part: int) -> Tuple[bytes, np.ndarray]:
-    ncol = len(cols)
+def _draw(cfg: dict, cols, part: int):
+    """Part ``part``'s rows as drawn, a chunk at a time: each row's pick
+    from each column's pool, label first."""
     rows = cfg["corpus"]["rows_per_part"]
     rng = rng_for(cfg, part)
-    texts, lengths = [], []
     for lo in range(0, rows, CHUNK):
         n = min(CHUNK, rows - lo)
+        yield np.stack([rng.integers(0, len(vals), n) for vals, _, _ in cols], 1)
+
+
+def make_part(cfg: dict, cols, part: int) -> Tuple[bytes, np.ndarray]:
+    ncol = len(cols)
+    texts, lengths = [], []
+    for picks in _draw(cfg, cols, part):
+        n = len(picks)
         chars = np.empty((n, ncol, WIDTH + 1), np.uint8)
         keep = np.ones((n, ncol, WIDTH + 1), bool)
         for c, (vals, cc, ck) in enumerate(cols):
-            pick = rng.integers(0, len(vals), n)
-            chars[:, c, :WIDTH] = cc[pick]
-            keep[:, c, :WIDTH] = ck[pick]
+            chars[:, c, :WIDTH] = cc[picks[:, c]]
+            keep[:, c, :WIDTH] = ck[picks[:, c]]
         chars[:, :, WIDTH] = ord(",")
         chars[:, -1, WIDTH] = ord("\n")
         t, ln = compact(chars.reshape(n, -1), keep.reshape(n, -1))
         texts.append(t)
         lengths.append(ln)
     return b"".join(texts), np.concatenate(lengths)
+
+
+def rows(cfg: dict, cols, part: int) -> Iterator[Dict[str, np.ndarray]]:
+    """Part ``part``'s rows as the reference parses its text (the label
+    column first, then the features at indices 0..27), a chunk at a
+    time, without the text."""
+    for picks in _draw(cfg, cols, part):
+        vals = np.stack([v[picks[:, c]] for c, (v, _, _) in enumerate(cols)], 1)
+        n, k = vals.shape
+        yield {"label": vals[:, 0], "length": np.full(n, k - 1),
+               "index": np.tile(np.arange(k - 1), n), "value": vals[:, 1:].reshape(-1)}
