@@ -27,8 +27,11 @@ Plants, each put in at the program's boundary:
   out of source order.
 - ``repeated``: each batch's second row replaced by its first where the
   batch is yielded.
-- ``twice``: under a chunk-level shuffle, each epoch reads its first
-  sub-shard again in place of its last (``InputSplitShuffle``).
+- ``twice``: the source reads its first file again in place of its
+  last (``Pipeline.from_uri`` given the URI so changed): every epoch
+  delivers one part of the corpus twice and another never, through
+  whichever engine parses it and whatever order a shuffle puts the
+  parts in.
 
 Prints one JSON line per seed and plant (``correct``, each number
 compared, and the run's epochs, engine, assembly path and locator
@@ -102,20 +105,13 @@ def _repeat_first(b):
             for k, v in b.items()}
 
 
-def _read_first_twice(before_first):
-    """``InputSplitShuffle.before_first`` that, after the epoch's order
-    is drawn, puts a split of the first sub-shard read in the place of
-    the last one read."""
-    from dmlc_tpu.io.input_split import InputSplit
-
-    def f(self):
-        self._subs = list(self.__dict__.setdefault("_own_subs", self._subs))
-        before_first(self)
-        n = self._num_shuffle_parts
-        self._subs[self._order[-1]] = InputSplit.create(
-            self._uri, self.part_index * n + int(self._order[0]), self.num_parts * n,
-            self._split_type, **self._kwargs)
-    return f
+def _first_file_twice(uri: str) -> str:
+    """``uri``, a ``;``-joined list of files, with its first file in the
+    place of its last."""
+    files = uri.split(";")
+    if len(files) < 2:
+        raise ValueError(f"the twice plant needs a source of two files or more: {uri!r}")
+    return ";".join(files[:-1] + files[:1])
 
 
 @contextlib.contextmanager
@@ -124,7 +120,6 @@ def planted(plant):
     ``prog`` leaves it sound."""
     import jax
     import jax.numpy as jnp
-    from dmlc_tpu.io.input_split_shuffle import InputSplitShuffle
     from dmlc_tpu.models import common
     from dmlc_tpu.models.common import SparseModelBase
     from dmlc_tpu.pipeline import CompiledPipeline, Pipeline
@@ -166,8 +161,10 @@ def planted(plant):
         if plant == "unshuffled":
             put(Pipeline, "shuffle", lambda self, *a, **kw: self)
         if plant == "twice":
-            put(InputSplitShuffle, "before_first",
-                _read_first_twice(InputSplitShuffle.before_first))
+            source = Pipeline.__dict__["from_uri"]
+            Pipeline.from_uri = staticmethod(
+                lambda uri, *a, **kw: source.__func__(_first_file_twice(uri), *a, **kw))
+            stack.callback(setattr, Pipeline, "from_uri", source)
         if plant == "exchange":
             def chip0(x, axis):
                 return jax.lax.psum(jnp.where(jax.lax.axis_index(axis) == 0, x, 0), axis)

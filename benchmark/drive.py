@@ -22,6 +22,12 @@ two more checks: no content delivered in an epoch more often than the
 rows of the part that hold it, over every row the window delivers (a
 key of each row's content is taken on the device as it is fed), and no
 checked window batch exactly where source order would put it.
+
+A traffic that states the parse engine its deployment needs
+(``"engine": "native"`` or ``"python"``) is held to it by one more
+check, ``engine_served``. The harness does not ask for that engine: it
+judges which engine served the epochs the run finished, so a program
+that falls back to another engine comes out not correct.
 """
 
 from __future__ import annotations
@@ -163,6 +169,17 @@ def served_by(stats: Optional[dict]) -> Dict[str, Optional[str]]:
     out = {"engine": engine, "assembly_path": path}
     out.update({"replay_tier": x["replay_tier"] for x in extra if "replay_tier" in x})
     return out
+
+
+def engine_served(traffic: dict, snapshots: List[Optional[dict]]) -> Optional[int]:
+    """0 where every epoch the run finished (``snapshots``, one stats
+    snapshot each) was parsed by the engine the traffic states, else 1;
+    a run that finished no epoch has not shown its engine, and reads 1.
+    None where the traffic states no engine."""
+    want = traffic.get("engine")
+    if want is None:
+        return None
+    return int(not snapshots or any(served_by(s)["engine"] != want for s in snapshots))
 
 
 def locate_rows(loc: "locate.Locator", stream: Stream, first: np.ndarray,
@@ -357,6 +374,9 @@ def run(name: str, seed: int, seconds: float, trace: bool, t0: float,
     part_rows = np.diff(first)
     done = [e for e in np.unique(epochs) if e < epoch]
     checks: Dict[str, Dict[str, Any]] = {}
+    engine = engine_served(traffic, snapshots)
+    if engine is not None:
+        checks["engine_served"] = {"value": engine}
     if done:
         gap = max(int(np.abs(num_rows[epochs == e].sum(axis=0) - part_rows).sum())
                   for e in done)

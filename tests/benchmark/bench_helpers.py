@@ -1,7 +1,8 @@
 """A checkout in miniature for the benchmark's CPU tests: the real
 BENCHMARK.json, traffic and limits files, with each configuration's
 corpus and batch cut to a size a test run holds, and two cells of its
-own whose rows arrive out of source order (``SHUFFLED``).
+own whose rows arrive out of source order (``SHUFFLED``), and two of
+the chunk-shuffled traffic that state an engine (``STATED``).
 
 As a script it writes such a checkout, or with ``--full`` one at the
 configurations' own sizes, for ``benchmark/control.py --root``:
@@ -27,6 +28,9 @@ SHUFFLED = {
     "criteo_tb.shuffled_chunks": [["shuffle", {"num_shuffle_parts": 16, "seed": RUN_SEED}]],
     "criteo_tb.shuffled_global": [["shuffle", {"global_seed": RUN_SEED}]],
 }
+# test-only cells of the chunk-shuffled traffic that state the engine
+# that must serve its parse: the one the program picks reads correct
+STATED = {"criteo_tb.shuffled_native": "native", "criteo_tb.shuffled_python": "python"}
 SMALL = {"criteo_tb_libsvm": dict(parts=3, rows_per_part=700, batch_rows=128),
          "higgs_csv": dict(parts=3, rows_per_part=500, batch_rows=128)}
 
@@ -62,13 +66,16 @@ def _add_shuffled(root: str) -> None:
     with open(os.path.join(root, "benchmark", "limits", "criteo_tb.stream_train.json")) as f:
         limits = json.load(f)
     limits.update(rows_repeated={"limit": 0}, rows_in_source_order={"limit": 2})
-    for cell, steps in SHUFFLED.items():
+    cells = {c: {"before_parse": steps} for c, steps in SHUFFLED.items()}
+    cells.update({c: {"before_parse": SHUFFLED["criteo_tb.shuffled_chunks"], "engine": e}
+                  for c, e in STATED.items()})
+    for cell, more in cells.items():
         mix = cell.split(".")[1]
         bench["workloads"].append({"name": cell, "config": "criteo_tb_libsvm",
                                    "traffic": mix, "chips": 1, "why": "test only"})
-        traffic = {"why": "test only", "before_parse": steps, "rows": "out_of_order",
-                   "consumer": "sgd"}
-        for d, name, obj in (("traffic", mix, traffic), ("limits", cell, limits)):
+        traffic = {"why": "test only", "rows": "out_of_order", "consumer": "sgd", **more}
+        held = dict(limits, engine_served={"limit": 0}) if "engine" in more else limits
+        for d, name, obj in (("traffic", mix, traffic), ("limits", cell, held)):
             with open(os.path.join(root, "benchmark", d, name + ".json"), "w") as f:
                 json.dump(obj, f)
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
@@ -77,4 +84,4 @@ def _add_shuffled(root: str) -> None:
 
 if __name__ == "__main__":
     out = tiny_root(sys.argv[1], full="--full" in sys.argv[2:])
-    print(json.dumps({"root": out[0], "cache": out[1], "cells": sorted(SHUFFLED)}))
+    print(json.dumps({"root": out[0], "cache": out[1], "cells": sorted({**SHUFFLED, **STATED})}))

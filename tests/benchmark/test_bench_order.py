@@ -192,6 +192,11 @@ def test_finished_epochs_count_their_parts_bytes_exactly(tmp_path, nparts):
     assert got[3] == pytest.approx(float((20 * part_bytes / np.diff(first)).sum()))
 
 
+# the stats snapshot of an epoch parsed by the Python golden
+GOLDEN = {"stages": [{"kind": "parse", "extra": {"bytes_read": 1}},
+                     {"kind": "assemble", "extra": {"assembly_path": "python-fused"}}]}
+
+
 def test_served_by_names_the_engine_and_the_assembly_path():
     with open(os.path.join(H.DATA, "stats.json")) as f:
         stats = json.load(f)
@@ -199,10 +204,24 @@ def test_served_by_names_the_engine_and_the_assembly_path():
         "engine": "native", "assembly_path": "native-padded"}
     assert drive.served_by(stats["page_replay"]["stats"][0]) == {
         "engine": None, "assembly_path": "python-fused", "replay_tier": "pages"}
-    golden = {"stages": [{"kind": "parse", "extra": {"bytes_read": 1}},
-                         {"kind": "assemble", "extra": {"assembly_path": "python-fused"}}]}
-    assert drive.served_by(golden) == {"engine": "python", "assembly_path": "python-fused"}
+    assert drive.served_by(GOLDEN) == {"engine": "python", "assembly_path": "python-fused"}
     assert drive.served_by(None) == {"engine": None, "assembly_path": None}
+
+
+@pytest.mark.parametrize("stated,epochs,want", [
+    (None, ["native"], None),        # no stated engine, no check
+    ("native", ["native", "native"], 0),
+    ("python", ["golden"], 0),
+    ("native", ["native", "golden"], 1),  # one epoch served by another
+    ("python", ["native"], 1),
+    ("native", [], 1),               # no finished epoch shows its engine
+])
+def test_engine_served_reads_every_finished_epoch(stated, epochs, want):
+    with open(os.path.join(H.DATA, "stats.json")) as f:
+        native = json.load(f)["stream_train"]["stats"][0]
+    snaps = [native if e == "native" else GOLDEN for e in epochs]
+    traffic = {"consumer": "sgd"} if stated is None else {"consumer": "sgd", "engine": stated}
+    assert drive.engine_served(traffic, snaps) == want
 
 
 def test_repeated_plant_repeats_the_first_row_of_each_shard():
@@ -218,29 +237,32 @@ def test_repeated_plant_repeats_the_first_row_of_each_shard():
         assert ref.rows_mismatched(got, want) == 0
 
 
-def test_twice_plant_reads_the_first_sub_shard_again(tmp_path):
+@pytest.mark.parametrize("order", ["in_order", "chunks"])
+def test_twice_plant_reads_the_first_sub_shard_again(tmp_path, order):
+    # a source of three files: under the plant, the program reads the
+    # first file's rows a second time and the last file's never, on the
+    # native engine in source order and on whichever engine the program
+    # picks under a chunk shuffle
     from collections import Counter
 
-    from dmlc_tpu.io.input_split_shuffle import InputSplitShuffle
-    path = tmp_path / "rows.txt"
-    path.write_bytes(b"".join(b"%d\n" % i for i in range(400)))
+    from dmlc_tpu.pipeline import Pipeline
+    files = []
+    for k in range(3):
+        files.append(str(tmp_path / f"part-{k}.txt"))
+        with open(files[-1], "wb") as f:
+            f.write(b"".join(b"1 %d:1\n" % (100 * k + i) for i in range(100)))
 
-    def epoch(split):
-        out = []
-        while (r := split.next_record()) is not None:
-            out.append(bytes(r).strip())
-        split.before_first()
-        return out
+    def rows(pipe):
+        built = (pipe.shuffle(4, seed=7).parse(format="libsvm") if order == "chunks"
+                 else pipe.parse(format="libsvm", engine="native")).build()
+        try:
+            return Counter(int(i) for block in built for i in block.index)
+        finally:
+            built.close()
 
-    sound = InputSplitShuffle(str(path), 0, 1, "text", 4, seed=7)
-    assert sorted(epoch(sound)) == sorted(b"%d" % i for i in range(400))
+    assert rows(Pipeline.from_uri(";".join(files))) == Counter(range(300))
     with control.planted("twice"):
-        split = InputSplitShuffle(str(path), 0, 1, "text", 4, seed=7)
-        for _ in range(2):
-            got = epoch(split)
-            n = Counter(got)
-            twice = [r for r in got if n[r] == 2]
-            # the epoch's first sub-shard comes again at its end, and
-            # another is never read
-            assert twice and got[:len(twice) // 2] == got[-(len(twice) // 2):]
-            assert max(n.values()) == 2 and len(n) < 400
+        got = rows(Pipeline.from_uri(";".join(files)))
+    assert got == Counter({**{i: 2 for i in range(100)}, **{i: 1 for i in range(100, 200)}})
+    # the program's entry is restored as the plant ends
+    assert rows(Pipeline.from_uri(";".join(files))) == Counter(range(300))
