@@ -3,7 +3,7 @@
 No reference counterpart (dmlc-core has no models); this is the canonical
 TPU consumer of the framework's data layout:
 
-- single-chip: flat padded CSR (parallel.pad_to_bucket) + segment-sum SpMV
+- single-chip: flat padded CSR (parallel.pad_to_bucket) + segmented-scan SpMV
 - multi-chip: global [D, ...] batches (parallel.make_global_batch) under
   shard_map over the mesh's data axis; gradients of replicated params are
   psum-reduced by construction. Parallelism is DATA parallelism — the only

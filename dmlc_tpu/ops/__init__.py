@@ -7,8 +7,8 @@ TPU-native consumers that make HBM-resident CSR batches useful
 
 from dmlc_tpu.ops.csr import (
     csr_to_padded_rows, spmv, csr_to_dense, segment_spmv, sdot_rows,
-    sharded_spmv, csr_row_ids,
+    sharded_spmv, csr_row_ids, csr_row_sum,
 )
 
 __all__ = ["csr_to_padded_rows", "spmv", "csr_to_dense", "segment_spmv",
-           "sdot_rows", "sharded_spmv", "csr_row_ids"]
+           "sdot_rows", "sharded_spmv", "csr_row_ids", "csr_row_sum"]

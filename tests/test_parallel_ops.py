@@ -13,9 +13,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 from dmlc_tpu.data.parser import Parser
 from dmlc_tpu.data.rowblock import RowBlock, RowBlockContainer
 from dmlc_tpu.models import SparseFMModel, SparseLinearModel
+from jax.extend import core as jcore
+
 from dmlc_tpu.ops import (
-    csr_row_ids, csr_to_dense, csr_to_padded_rows, sdot_rows, segment_spmv,
-    sharded_spmv, spmv,
+    csr_row_ids, csr_row_sum, csr_to_dense, csr_to_padded_rows, sdot_rows,
+    segment_spmv, sharded_spmv, spmv,
 )
 from dmlc_tpu.parallel import (
     DeviceIter, ShardedRowBlockIter, device_prefetch, empty_block,
@@ -60,7 +62,99 @@ ROW_ID_CASES = {
 }
 
 
+ROW_SUM_CASES = {
+    "empty_first_middle_last": lambda rng: _offsets([0, 3, 0, 0, 5, 2, 0]),
+    "all_empty": lambda rng: _offsets(np.zeros(16, np.int64), 128),
+    "no_nonzeros": lambda rng: _offsets(np.zeros(5, np.int64)),
+    # indices past the rows stay nonzero: only value 0 makes them neutral
+    "padded_tail": lambda rng: _offsets([3, 4, 1], 200),
+    "single_nonzero_rows": lambda rng: _offsets(np.ones(50, np.int64)),
+    # runs across one and across nine 128-lane rows
+    "long_rows": lambda rng: _offsets([130, 3, 1100, 0, 7], 1300),
+    "nnz_not_lane_multiple": lambda rng: _offsets(rng.randint(0, 8, 40),
+                                                  333),
+    "criteo_like": lambda rng: _offsets(np.full(64, 39), 64 * 39 + 64),
+}
+
+
+def _nnz_indexed(jaxpr, nnz):
+    """Gathers and scatters, in ``jaxpr`` and every jaxpr inside it,
+    whose index operand has ``nnz`` entries."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name.startswith(("gather", "scatter")):
+            count += eqn.invars[1].aval.shape[0] == nnz
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    count += _nnz_indexed(sub, nnz)
+    return count
+
+
 class TestCsrOps:
+    @pytest.mark.parametrize("x64", [False, True], ids=["int32", "int64"])
+    @pytest.mark.parametrize("case", sorted(ROW_SUM_CASES))
+    def test_row_sums_match_float64(self, rng, case, x64):
+        """csr_row_sum and segment_spmv, values and their gradients with
+        respect to the weights and the values, against NumPy float64."""
+        offset, nnz = ROW_SUM_CASES[case](rng)
+        n, ncol = len(offset) - 1, 50
+        index = rng.randint(1, ncol, nnz).astype(np.uint32)
+        value = rng.randn(nnz).astype(np.float32)
+        value[offset[-1]:] = 0
+        w = rng.randn(ncol).astype(np.float32)
+        cot = rng.randn(n).astype(np.float32)
+        rows = np.searchsorted(offset, np.arange(nnz), side="right") - 1
+        live = rows < n
+        terms = value.astype(np.float64) * w[index]
+        gold = np.bincount(rows[live], terms[live], minlength=n)
+        scale = np.bincount(rows[live], np.abs(terms[live]), minlength=n)
+        row_cot = np.where(live, cot[np.minimum(rows, n - 1)], 0.0)
+        gold_dvalue = row_cot * w[index]
+        gold_dw = np.bincount(index, row_cot * value, minlength=ncol)
+        dw_scale = np.bincount(index, np.abs(row_cot * value),
+                               minlength=ncol)
+        dtype = np.int64 if x64 else np.int32
+        with jax.enable_x64(x64):
+            off = jnp.asarray(offset.astype(dtype))
+            assert off.dtype == dtype
+            y, pull = jax.vjp(
+                lambda v, wt: segment_spmv(off, jnp.asarray(index), v, wt,
+                                           num_rows=n),
+                jnp.asarray(value), jnp.asarray(w))
+            dvalue, dw = pull(jnp.asarray(cot))
+            sums, pull = jax.vjp(jax.jit(lambda c: csr_row_sum(off, c, n)),
+                                 jnp.asarray(terms.astype(np.float32)))
+            (dterms,) = pull(jnp.asarray(cot))
+        eps = np.finfo(np.float32).eps
+        for got in (y, sums):
+            assert got.dtype == np.float32
+            assert np.all(np.abs(np.asarray(got) - gold)
+                          <= 16 * eps * scale + 1e-30)
+        np.testing.assert_allclose(np.asarray(dvalue), gold_dvalue,
+                                   rtol=4 * eps, atol=0)
+        np.testing.assert_array_equal(np.asarray(dterms), row_cot)
+        assert np.all(np.abs(np.asarray(dw) - gold_dw)
+                      <= 16 * eps * dw_scale + 1e-30)
+
+    def test_row_sums_index_only_the_offsets(self):
+        """The step's only nnz-long gather and scatter are on the weight
+        side (the weight gather, the gradient's scatter-add): the row
+        side indexes the n + 1 offsets alone."""
+        rows, nnz, ncol = 16, 160, 50
+        batch = {"offset": jnp.zeros(rows + 1, jnp.int32),
+                 "label": jnp.zeros(rows, jnp.float32),
+                 "weight": jnp.zeros(rows, jnp.float32),
+                 "index": jnp.zeros(nnz, jnp.uint32),
+                 "value": jnp.zeros(nnz, jnp.float32)}
+        model = SparseLinearModel(ncol)
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(model.loss))(
+            model.init_params(), batch)
+        assert _nnz_indexed(jaxpr.jaxpr, nnz) == 2
+
     @pytest.mark.parametrize("x64", [False, True], ids=["int32", "int64"])
     @pytest.mark.parametrize("case", sorted(ROW_ID_CASES))
     def test_csr_row_ids_matches_searchsorted(self, rng, case, x64):

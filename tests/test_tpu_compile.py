@@ -9,6 +9,7 @@ compiler at import would make every xdist worker take libtpu's lock.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -64,6 +65,14 @@ def _params(sharding):
             "b": jax.ShapeDtypeStruct((), jnp.float32, sharding=sharding)}
 
 
+def _nnz_indexed(hlo: str, nnz: int) -> int:
+    """Gathers and scatters in compiled HLO text whose index operand (the
+    second) has ``nnz`` entries; HLO names are unique in a module."""
+    shapes = dict(re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", hlo))
+    ops = re.findall(r" (?:gather|scatter)\(%[\w.\-]+, (%[\w.\-]+)", hlo)
+    return sum(shapes[i].split(",")[0] == str(nnz) for i in ops)
+
+
 def test_train_step_one_chip(topo):
     from dmlc_tpu.models import SparseLinearModel
     one = SingleDeviceSharding(topo.devices[0])
@@ -74,7 +83,11 @@ def test_train_step_one_chip(topo):
     # params in and out, one batch, scratch: far inside 16 GB of HBM
     assert mem.argument_size_in_bytes < 64 << 20
     # row ids are counted, not searched: no serial loop in the step
-    assert " while(" not in compiled.as_text()
+    hlo = compiled.as_text()
+    assert " while(" not in hlo
+    # row sums by a segmented scan: the only nnz-long gather and scatter
+    # are the weight gather and the gradient's scatter-add
+    assert _nnz_indexed(hlo, NNZ) == 2
 
 
 def test_sharded_spmv(mesh):
