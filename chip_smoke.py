@@ -3,7 +3,7 @@
     python chip_smoke.py             # one chip
     python chip_smoke.py --chips 4   # the data-parallel path on 4 chips
 
-One chip: a criteo-shaped libsvm corpus (bench.py's generator, 256 MB)
+One chip: a criteo-shaped libsvm corpus (``ensure_data``, 256 MB)
 → native parse and padded batch assembly → HBM through the
 Pipeline API → ``SparseLinearModel(2**20).train_step`` on the
 device-resident batches. Checks: the epoch used the native padded
@@ -31,10 +31,12 @@ import hashlib
 import json
 import os
 import sys
+import tempfile
 import time
 
+DATA = os.path.join(tempfile.gettempdir(), "dmlc_tpu_chip_smoke.libsvm")
 CORPUS_MB = 256
-ROWS = 8192               # rows per batch (bench.py's shape)
+ROWS = 8192               # rows per batch
 NNZ_BUCKET = ROWS * 45    # the generator writes < 45 features per row
 NUM_FEATURES = 2 ** 20
 STEPS = 20
@@ -376,6 +378,46 @@ def phase_sharded(path: str, devices, steps: int = STEPS,
             "loss_last": losses[-1], **errs}
 
 
+def ensure_data(path: str = DATA, size_mb: int = CORPUS_MB) -> int:
+    """Write a criteo-shaped libsvm corpus of ~size_mb MB to ``path``:
+    a 4000-row block made from seed 0 and repeated, 25-44 features per
+    row over a 10^6 index space, labels 1 with probability 1/4 (about
+    Criteo's click rate, so a model's bias has a gradient to follow).
+    Written anew on every call (well under a second for 256 MB), to a
+    temp file renamed over ``path``: whatever was at ``path`` before,
+    from this generator or another, is never reused."""
+    import numpy as np
+    want = size_mb << 20
+    rng = np.random.default_rng(0)
+    rows = []
+    for _ in range(4000):
+        nnz = int(rng.integers(25, 45))
+        idx = np.sort(rng.choice(10 ** 6, nnz, replace=False))
+        vals = rng.random(nnz)
+        rows.append(" ".join(f"{j}:{v:.6f}" for j, v in zip(idx, vals)))
+    labels = rng.random(len(rows)) < 0.25
+    block = "".join(f"{int(y)} {r}\n" for y, r in zip(labels, rows)).encode()
+    reps = max(1, want // len(block))
+    tmp = f"{path}.tmp{os.getpid()}"
+    with open(tmp, "wb") as f:
+        for _ in range(reps):
+            f.write(block)
+    os.replace(tmp, path)
+    return os.path.getsize(path)
+
+
+def require_tpu():
+    """The device to check, or exit 3: a run anywhere but on the chip
+    is not this smoke test's run."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print(f"no TPU: JAX found {dev.platform} ({dev}); refusing to "
+              "run", file=sys.stderr, flush=True)
+        sys.exit(3)
+    return dev
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
@@ -383,8 +425,7 @@ def main(argv=None) -> int:
 
     import jax
 
-    import bench
-    dev = bench.require_tpu()
+    dev = require_tpu()
     devices = jax.devices()
     if len(devices) < args.chips:
         print(f"chip_smoke: --chips {args.chips} but JAX found "
@@ -403,15 +444,15 @@ def main(argv=None) -> int:
     native.get_lib()  # builds from engine.cc on first use; fails loudly
     emit("native", build_or_load_s=time.perf_counter() - t)
     t = time.perf_counter()
-    size = bench.ensure_data(bench.DATA, CORPUS_MB)
-    emit("corpus", path=bench.DATA, bytes=size, s=time.perf_counter() - t)
+    size = ensure_data(DATA, CORPUS_MB)
+    emit("corpus", path=DATA, bytes=size, s=time.perf_counter() - t)
     if args.chips == 1:
-        batches, host, report = phase_ingest(bench.DATA, dev)
+        batches, host, report = phase_ingest(DATA, dev)
         emit("ingest", **report)
-        emit("golden_parity", **phase_golden_parity(bench.DATA))
+        emit("golden_parity", **phase_golden_parity(DATA))
         emit("consumer", **phase_consumer(batches, host))
     else:
-        emit("sharded", **phase_sharded(bench.DATA, devices))
+        emit("sharded", **phase_sharded(DATA, devices))
     emit("compile", **cache_stats())
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

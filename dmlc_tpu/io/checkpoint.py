@@ -46,7 +46,7 @@ never staging the whole tree on the host:
   its OWN pages into the page store, and takes the rest from the
   owners' ``/pages`` tier + singleflight — each rank pays ~1/N of the
   wire (``checkpoint.restore.{local,peer,wire}_bytes`` counters prove
-  the split; bench_suite config 21 measures it). Without a gang the
+  the split). Without a gang the
   same path degrades to all-wire, same bytes as today.
 """
 

@@ -1,5 +1,5 @@
-"""On-disk fake object store — the test/bench backend of the remote
-I/O plane.
+"""On-disk fake object store — the test backend of the remote I/O
+plane.
 
 The container this repo grows in has no network and no cloud
 credentials (SURVEY §7), so the ``obj://`` plane is exercised against
@@ -10,9 +10,8 @@ rather than a stub:
 
 - **latency/bandwidth shaping** — every GET *and every PUT* pays
   ``latency_s`` plus ``bytes / bandwidth`` of sleep, so cold-vs-warm
-  epoch benchmarks (bench_suite config 11) and multipart-vs-single-shot
-  write benchmarks (config 21) measure a believable wire, not a local
-  read;
+  epochs and multipart-vs-single-shot writes see a believable wire,
+  not a local read;
 - **first-class chaos** — the client seams (``io.objstore.get`` etc.,
   see fs.py) run under ``resilience.guarded()``, so an armed
   :class:`~dmlc_tpu.resilience.inject.FaultPlan` targets emulator

@@ -68,8 +68,8 @@ def default_store_dir() -> str:
     directory — one dir, one sweep, one budget.
     ``DMLC_TPU_PAGESTORE_DIR`` overrides it (read per call, so a gang
     worker sharing a host with its peers can give each rank its OWN
-    store — what the objstore peer tier's ``/pages`` endpoint and the
-    config-15 gang bench rely on)."""
+    store — what the objstore peer tier's ``/pages`` endpoint and its
+    gang tests rely on)."""
     env = os.environ.get(ENV_STORE_DIR)
     if env:
         return env

@@ -144,7 +144,7 @@ class TPUSeekStream(SeekStream):
         median vs 1.14 unpooled at 4 MB; 8 MB+ never better), measured
         through a shared-chip transfer path whose rate shaping a plain
         v5e host does not have: not yet re-measured on the v5e — see
-        BASELINE.md "Transfer ceiling" and dmlc_tpu.bench_transfer."""
+        BASELINE.md "Transfer ceiling"."""
         import jax
         from dmlc_tpu.utils.memory import thread_local_pool
         check(lookahead >= 1, "lookahead must be >= 1")

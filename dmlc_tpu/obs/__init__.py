@@ -28,7 +28,7 @@ Python and native layers (docs/observability.md):
   wall-anchored timeline (per-rank series, rollups, explicit
   unreachable-rank gaps; served at /gang);
 - :mod:`~dmlc_tpu.obs.analyze` — bottleneck attribution (the
-  structured bound verdict bench.py embeds and /analyze serves) and
+  structured bound verdict /analyze serves) and
   band-aware BENCH-to-BENCH regression comparison;
 - :mod:`~dmlc_tpu.obs.profile` — the continuous sampling profiler:
   merged Python+native flamegraphs (sys._current_frames + the

@@ -1,13 +1,12 @@
 """Bottleneck attribution: turn recorded telemetry into a verdict.
 
-The BENCH r1→r7 trajectory (0.34 → 0.58 sustained GB/s) has been
-interpreted by a human reading JSON files: stage waits here, credit
-gauges there, assembly path in a third key. This module is that
-judgment as code, decomposing an epoch from data the plane ALREADY
-records — ``StageProbe`` wait totals, the device stage's
+Reading an epoch by hand means joining stage waits, credit gauges and
+the assembly path from three places. This module is that judgment as
+code, decomposing an epoch from data the plane ALREADY records —
+``StageProbe`` wait totals, the device stage's
 ``xfer_wait_s``/``staging_assemble_s`` extras, the fused engine's
-``assemble_s``, pagestore/objstore hit counters, and the credit-gauge
-bands bench.py computes — into one structured verdict:
+``assemble_s``, pagestore/objstore hit counters, and host credit-gauge
+readings when a caller supplies them — into one structured verdict:
 
 ``{"schema": 4, "epoch": <monotonic>, "verdict_id": "v<epoch>-<digest>",
 "tenant": <label or None>, "bound": "parse" | "assemble" | "xfer" |
@@ -36,7 +35,7 @@ profiler runs (the verdict says which stage, not which frame).
 
 The key set is pinned by ``scripts/lint.py``'s verdict-schema gate (a
 literal-dict key check like the metric-name gate), so the ``/analyze``
-endpoint, bench.py's embedded ``"analysis"`` block, and
+endpoint, the ``"analysis"`` blocks of archived BENCH JSON files, and
 ``scripts/obsctl.py`` can never drift apart. Every evidence entry
 names the MEASURED quantity it rests on — two legs with different
 stage waits can share a ``bound`` but never share evidence.
@@ -96,7 +95,7 @@ _CONSUMER_WAIT_FRAC = 0.15
 def gauge_band(g: Optional[float]) -> str:
     """Credit-comparability band of one host-memcpy gauge reading
     (BASELINE.md "Credit-recovery profile"). The ONE implementation —
-    bench.py and compare() both read bands through here."""
+    attribute() and compare() both read bands through here."""
     if g is None:
         return "unknown"
     if g < 1.0:
@@ -189,10 +188,11 @@ def attribute(pipeline_snap: Dict[str, Any],
     ``pipeline`` collector in a registry snapshot, or the ``pipeline``
     block of a BENCH JSON). ``metrics`` is an optional registry
     snapshot for the wire-side counters (pagestore/objstore hit
-    rates). ``epoch_gauges``/``run_band`` carry bench.py's credit
-    gauges when available — without them the credit-limited bound
-    cannot be claimed and the verdict says so. ``profile_doc`` is an
-    optional :mod:`dmlc_tpu.obs.profile` ``to_dict()`` payload for
+    rates). ``epoch_gauges``/``run_band`` carry host credit-gauge
+    readings when the caller has them — without them the
+    credit-limited bound cannot be claimed and the verdict says so.
+    ``profile_doc`` is an optional :mod:`dmlc_tpu.obs.profile`
+    ``to_dict()`` payload for
     the ``hot_frames`` evidence; when omitted, the process's
     installed sampling profiler (if any) is read.
 
@@ -514,8 +514,8 @@ def slo_verdict(name: str, row: Dict[str, Any],
 # ------------------------------------------------------- BENCH compare
 
 def load_bench(path_or_doc) -> Dict[str, Any]:
-    """Load a BENCH JSON: either the raw one-line dict bench.py
-    prints, or the campaign wrapper the BENCH_r0*.json archive uses
+    """Load a BENCH JSON: either a raw one-line result dict, or the
+    campaign wrapper the BENCH_r0*.json archive uses
     (``{"n", "cmd", "rc", "tail", "parsed"}`` — ``parsed`` is the
     bench line; older wrappers may only carry it inside ``tail``)."""
     if isinstance(path_or_doc, dict):
@@ -523,9 +523,9 @@ def load_bench(path_or_doc) -> Dict[str, Any]:
     else:
         with open(path_or_doc) as f:
             doc = json.load(f)
-    # bench_suite config lines (config 14 recio_native etc.) carry
-    # "config" + "gbps": comparable band-for-band via their
-    # epoch_gauges/gbps fallback in _bands_of
+    # per-configuration result lines carry "config" + "gbps":
+    # comparable band-for-band via their epoch_gauges/gbps fallback
+    # in _bands_of
     if "metric" in doc or "pipeline" in doc or "config" in doc:
         return doc
     if isinstance(doc.get("parsed"), dict):
@@ -562,7 +562,7 @@ def _bands_of(doc: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
     if value is None:
         value = doc.get("value")
     if value is None:
-        value = doc.get("gbps")  # bench_suite config lines
+        value = doc.get("gbps")  # per-configuration result lines
     if value is not None:
         out[band] = {"sustained": value, "epochs": doc.get("epochs")}
     return out

@@ -247,7 +247,7 @@ class Controller:
     """The between-epoch controller; see the module docstring.
 
     Feed it epochs either through :meth:`observe` (a stats snapshot —
-    the manual path benches and tests drive) or let an INSTALLED
+    the manual path tests drive) or let an INSTALLED
     controller adopt pipelines automatically (CompiledPipeline calls
     :meth:`observe_pipeline` at each epoch end when one is active).
     """
@@ -286,17 +286,17 @@ class Controller:
         # pipeline's traffic — cannot flip a local epoch's verdict to
         # wire-bound
         self._prev_counters: Dict[Any, Dict[str, Any]] = {}
-        # recent host-credit gauges fed by the measurement loop
-        # (bench.py's memcpy gauge): without them the credit-limited
-        # freeze cannot fire — attribute() says so in the band
+        # recent host-credit gauges fed through note_gauge(): without
+        # them the credit-limited freeze cannot fire — attribute()
+        # says so in the band
         self._gauges: List[float] = []
         self._observed = 0  # epochs observed, all sources
         self._metrics_key = self.registry.register(
             "control", self, Controller._collect)
 
     def note_gauge(self, gauge: float) -> None:
-        """Feed one pre-epoch host-credit gauge reading (bench.py's
-        memcpy gauge); the next :meth:`observe` without explicit
+        """Feed one pre-epoch host-credit gauge reading (a host memcpy
+        rate in GB/s); the next :meth:`observe` without explicit
         ``epoch_gauges`` judges the climate from the recent readings."""
         with self._lock:
             self._gauges.append(float(gauge))
@@ -425,7 +425,7 @@ class Controller:
                 source: Any = None) -> Dict[str, Any]:
         """Feed one completed epoch; returns the primary decision
         record appended to the ledger. ``verdict`` overrides the
-        attribution (bench embeds the one it already computed);
+        attribution (a caller that already computed one passes it);
         otherwise the epoch is attributed from ``metrics`` (default:
         the registry snapshot, wire counters delta-scoped)."""
         from dmlc_tpu.obs import analyze as _analyze
@@ -689,7 +689,7 @@ def uninstall() -> None:
 def detach() -> Optional[Controller]:
     """Suspend the installed controller WITHOUT closing it — returns
     it so the caller can ``install()`` it back. For probes that must
-    run a pipeline under their OWN controller (bench config 16): two
+    run a pipeline under their OWN controller: two
     movers on one pipeline would break the one-mover-per-process
     invariant and judge each other's trials. The suspended
     controller's registry collector is unregistered (so the caller's

@@ -192,11 +192,11 @@ class ShardedRowBlockIter:
         # within agreement_cache_bytes of RAW bytes stay in memory
         # (steady RSS ~ raw text size, not the several-x padded size
         # the r5 tee retained); larger rounds spill to a binary page
-        # file and replay at page rate (config 8: 1.4-2.0 GB/s text-
-        # equivalent vs the 0.22 GB/s parse path). Guarded by a
-        # per-file fingerprint captured before the cached parse: ANY
-        # mismatch falls back to the legacy re-parse epoch, whose count
-        # assertions implement the exact mutation semantics
+        # file and replay at page rate (1.4-2.0 GB/s text-equivalent
+        # vs the 0.22 GB/s parse path, measured on the r4 CPU box).
+        # Guarded by a per-file fingerprint captured before the cached
+        # parse: ANY mismatch falls back to the legacy re-parse epoch,
+        # whose count assertions implement the exact mutation semantics
         # (truncation/rewrite raise, appends stay invisible) — replay
         # is a pure optimization, never a semantics change. Retained
         # blocks are written once and only read afterwards, so CPU-

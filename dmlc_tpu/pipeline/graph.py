@@ -26,8 +26,7 @@ Ownership contract (the RowBlock lifetime rules, composed once here so
 every stage agrees): a stage yields items valid until the consumer's
 next pull. Buffering stages (``prefetch``) take ownership of ephemeral
 native-engine blocks by detaching their arena lease (falling back to a
-copy); ``to_device`` holds the lease until the async transfer lands —
-the exact discipline bench.py hand-wired.
+copy); ``to_device`` holds the lease until the async transfer lands.
 """
 
 from __future__ import annotations
@@ -462,8 +461,8 @@ class _CacheRunner(_RunnerBase):
 
     def finalize_epoch(self) -> None:
         # which replay tier served the epoch — the autotuner must not
-        # judge a knob trial across a tier flip, and bench JSON readers
-        # need to know which regime a number came from
+        # judge a knob trial across a tier flip, and readers of the
+        # stats need to know which regime a number came from
         self.probe.extra["replay_tier"] = self.tier
 
     def close(self) -> None:
@@ -627,8 +626,8 @@ class _PadBatchRunner(_RunnerBase):
             yield cut(pending)
 
     def finalize_epoch(self) -> None:
-        # which rung assembled the epoch's batches — bench attributes
-        # wins to native-padded vs python-fused with this field
+        # which rung assembled the epoch's batches — the benchmark's
+        # result line and obs.analyze's evidence read this field
         self.probe.extra["assembly_path"] = "python-fused"
         self.probe.extra["assemble_s"] = round(self._assemble_s, 6)
 
@@ -801,11 +800,10 @@ class _PrefetchRunner(_RunnerBase):
 
 
 class _DeviceRunner(_RunnerBase):
-    """Async host→device transfers with a bounded in-flight window —
-    the parse-to-HBM discipline bench.py hand-wired: device_put is
-    enqueued immediately, the arena lease (native engine) is held until
-    that transfer is drained, and ``window`` transfers ride under the
-    upstream's work."""
+    """Async host→device transfers with a bounded in-flight window:
+    device_put is enqueued immediately, the arena lease (native
+    engine) is held until that transfer is drained, and ``window``
+    transfers ride under the upstream's work."""
 
     kind = "to_device"
 

@@ -5,8 +5,7 @@ carries a :class:`StageProbe`. The probe sits at the pull site: each time
 the downstream consumer asks the stage for an item it records
 
 - **wait time** — seconds the consumer blocked waiting for the stage to
-  deliver (the stage's un-overlapped latency; the quantity bench.py's
-  hand-wired loop called ``pull-wait``),
+  deliver (the stage's un-overlapped latency),
 - **items / rows / bytes** — volume counters for throughput,
 - **queue occupancy** — for queue-backed stages (``prefetch``, the
   parser's chunk prefetch), a per-pull sample of ``qsize/capacity`` so
@@ -14,8 +13,8 @@ the downstream consumer asks the stage for an item it records
   consumer-bound (queue full) stages.
 
 ``snapshot()`` freezes one epoch of probes into a plain-JSON dict with a
-versioned schema (``PIPELINE_STATS_SCHEMA``) — the shape bench.py emits
-into BENCH JSON and tests/test_pipeline.py pins.
+versioned schema (``PIPELINE_STATS_SCHEMA``) — the shape
+``obs.analyze.attribute`` reads and tests/test_pipeline.py pins.
 
 Stage-specific ``extra`` fields (additive, schema version unchanged):
 

@@ -1,8 +1,7 @@
 """Where the entry points keep JAX's persistent compile cache.
 
-Called by ``chip_smoke.py``, ``bench.py`` and ``bench_suite.main``
-before their first compile — never at import of ``dmlc_tpu``, so a
-library user's own cache settings stand.
+Called by ``chip_smoke.py`` before its first compile — never at import
+of ``dmlc_tpu``, so a library user's own cache settings stand.
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; the
   directory is left alone.

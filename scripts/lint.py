@@ -33,8 +33,8 @@ without the tools baked in:
   pinned here, and any literal dict that claims to be a verdict
   (``"bound"`` + ``"evidence"`` keys) anywhere in ``dmlc_tpu/`` or
   ``scripts/`` must match it exactly — the ``/analyze`` endpoint,
-  bench JSON ``"analysis"`` blocks, and ``scripts/obsctl.py`` can
-  never drift apart.
+  the ``"analysis"`` blocks of archived BENCH JSON files, and
+  ``scripts/obsctl.py`` can never drift apart.
 - **Knob gate** (always run, AST-based): steady-state knob mutation —
   ``.set_capacity()`` calls, ``.prefetch_depth``/``.window`` attribute
   assignment, ``objstore.configure()`` with coalesce/parallel/
@@ -156,15 +156,12 @@ def builtin_lint(paths: List[str]) -> List[str]:
     return findings
 
 
-# pre-obs surfaces, pinned (package-relative paths). print(): the two
-# CLI-style emitters whose stdout IS their interface and the build
-# script. stats(): the five shapes that now REGISTER into
+# pre-obs surfaces, pinned (package-relative paths). print(): the build
+# script, whose stdout IS its interface. stats(): the five shapes that now REGISTER into
 # dmlc_tpu.obs.metrics (the methods stay for their callers). New code
 # uses obs.metrics / obs.log instead of growing this list.
 PRINT_ALLOWED = {
     "dmlc_tpu/native/build.py",
-    "dmlc_tpu/bench_transfer.py",
-    "dmlc_tpu/bench_suite.py",
 }
 STATS_ALLOWED = {
     "dmlc_tpu/data/threaded_iter.py",
@@ -279,12 +276,10 @@ def metric_lint(paths: List[str],
 # store stamps/accounts bytes. A direct open()/os.stat on a data path
 # elsewhere in the package silently bypasses all of it. The pinned
 # exceptions are files whose bytes are NOT data-path bytes: telemetry
-# output (trace exports, flight bundles, stall reports), bench corpus
-# builders and result JSON, launcher log capture, and the config file.
+# output (trace exports, flight bundles, stall reports), archived
+# result JSON, launcher log capture, and the config file.
 # The list shrinks, it does not grow.
 IO_SEAM_ALLOWED = {
-    "dmlc_tpu/bench_mp_worker.py",   # gang-worker result JSON
-    "dmlc_tpu/bench_suite.py",       # corpus builders / BENCH JSON
     "dmlc_tpu/native/build.py",      # build tooling (zlib link probe)
     "dmlc_tpu/obs/analyze.py",       # BENCH result JSON (compare)
     "dmlc_tpu/obs/export.py",        # trace JSON export
@@ -378,20 +373,19 @@ def codec_lint(paths: List[str],
 # pyarrow is a BOUNDARY, not a dependency (ABI 8): the native engine
 # decodes parquet pages itself, and the only package code allowed to
 # lean on pyarrow is the frozen golden (data/parquet_parser.py — the
-# byte-parity reference and the engine="auto" fallback) and
-# bench_suite.py's corpus makers. A pyarrow import anywhere else would
+# byte-parity reference and the engine="auto" fallback). A pyarrow
+# import anywhere else would
 # silently re-introduce the Python-bound decode wall the native lane
 # exists to remove — and break the package on hosts without pyarrow.
 # The list shrinks, it does not grow.
-ARROW_ALLOWED = {"dmlc_tpu/data/parquet_parser.py",
-                 "dmlc_tpu/bench_suite.py"}
+ARROW_ALLOWED = {"dmlc_tpu/data/parquet_parser.py"}
 _ARROW_MODULES = {"pyarrow"}
 
 
 def arrow_lint(paths: List[str],
                trees: Optional[dict] = None) -> List[str]:
-    """The pyarrow gate: imports confined to the parquet golden and
-    the bench corpus makers (see above)."""
+    """The pyarrow gate: imports confined to the parquet golden (see
+    above)."""
     if trees is None:
         trees = _parse_package_trees(paths)
     findings: List[str] = []
@@ -743,8 +737,8 @@ def row_loop_lint(paths: List[str],
 
 
 # The analysis-verdict schema (dmlc_tpu/obs/analyze.py VERDICT_KEYS):
-# the /analyze endpoint, bench.py's embedded "analysis" block, config
-# 13's acceptance assert, and scripts/obsctl.py all read THIS key set.
+# the /analyze endpoint, the "analysis" blocks of archived BENCH JSON
+# files, and scripts/obsctl.py all read THIS key set.
 # The pin below is the one source of truth the gate checks everything
 # against — change the schema by changing both, consciously.
 VERDICT_KEYS = ("schema", "epoch", "verdict_id", "tenant", "bound",
@@ -847,13 +841,9 @@ KNOB_MUTATION_ALLOWED = {
     "dmlc_tpu/parallel/sharded.py",    # initial prefetch_depth
 }
 # configure(coalesce=/parallel=/codec_level=) additionally allowed
-# where the option plane is DEFINED and where bench corpora set up
-# measurement variants (a bench config comparing codec on/off is an
-# experiment, not a hand-tuned steady-state constant)
+# where the option plane is DEFINED
 KNOB_CONFIGURE_ALLOWED = KNOB_MUTATION_ALLOWED | {
     "dmlc_tpu/io/objstore/fs.py",      # configure() itself
-    "dmlc_tpu/bench_suite.py",         # measurement variants
-    "dmlc_tpu/bench_peer_worker.py",   # gang-bench wire setup
 }
 _KNOB_ATTRS = {"prefetch_depth", "window"}
 _KNOB_CONFIGURE_KWARGS = {"coalesce", "parallel", "codec_level"}

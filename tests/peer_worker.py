@@ -1,5 +1,5 @@
-"""Worker for bench_suite config 15 (peer_hydrate) and the gang
-acceptance test in tests/test_peer.py.
+"""Gang worker for the peer-tier acceptance tests (tests/test_peer.py)
+and the flow-linked trace test (tests/test_rpc.py).
 
 Run under ``parallel.launch_local(serve_ports=True)`` as a REAL
 N-process gang: each rank gets its OWN page-store root (simulating
@@ -20,7 +20,7 @@ No jax: ranks coordinate through tiny file barriers in ``out_dir``
 ``launch_local`` does — including hosts whose jaxlib cannot do
 multiprocess-CPU collectives.
 
-Usage: bench_peer_worker.py <obj_uri> <out_dir> <block_bytes> <coalesce>
+Usage: peer_worker.py <obj_uri> <out_dir> <block_bytes> <coalesce>
 """
 
 import hashlib
@@ -93,7 +93,7 @@ def main() -> int:
                            max_delay_s=0.4))
     srv = serve_if_env()
     if srv is None:
-        raise RuntimeError("bench_peer_worker needs "
+        raise RuntimeError("peer_worker needs "
                            "launch_local(serve_ports=...)")
     from dmlc_tpu.rendezvous import install_if_env as rndv_if_env
     rndv = rndv_if_env()  # DMLC_TPU_RNDV_URI/PORT: elastic membership

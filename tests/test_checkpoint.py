@@ -569,6 +569,59 @@ class TestRemoteCheckpoint:
         assert ck2.last_restore_wire_bytes == ck2.last_restore_bytes_read
         assert ck2.last_restore_bytes_read > 0
 
+    def test_different_world_restore_fans_out_over_peers(
+            self, remote, tmp_path, monkeypatch):
+        """A three-writer save restored by a two-rank gang: each rank
+        wire-fetches only the pages ``content_owner`` assigns it at
+        world 2 and takes the rest from its peer's ``/pages`` tier.
+        Seen from rank 0, with rank 1 staged and served in-process:
+        every leaf restores identical, nothing counts as local, and
+        the bytes split between the peer and the wire, the wire
+        carrying at most 0.60 of them."""
+        import dmlc_tpu.io.pagestore as ps
+        from dmlc_tpu.io.objstore import peer as peer_mod
+        from dmlc_tpu.obs.serve import StatusServer
+        trees = [{f"w{w}": {f"l{i}": np.random.RandomState(100 * w + i)
+                            .rand(2048).astype(np.float32)
+                            for i in range(8)}} for w in range(3)]
+        saver = ShardedCheckpoint("obj://b/ck")
+        for w in (1, 2, 0):  # the committing writer saves last
+            saver.save(5, trees[w], writer=w, num_writers=3)
+        stores = [str(tmp_path / f"rank{r}") for r in range(2)]
+        for d in stores:
+            os.makedirs(d)
+        servers = [StatusServer(pages_root=d) for d in stores]
+        ports = [srv.port for srv in servers]
+        try:
+            # rank 1 stages its cut of the pages first
+            monkeypatch.setattr(ps, "default_store_dir",
+                                lambda: stores[1])
+            peer_mod.configure(ports=ports, self_port=ports[1])
+            ShardedCheckpoint("obj://b/ck").prefetch()
+            # rank 0: its own cut from the wire, the rest from rank 1
+            monkeypatch.setattr(ps, "default_store_dir",
+                                lambda: stores[0])
+            peer_mod.configure(ports=ports, self_port=ports[0])
+            ck = ShardedCheckpoint("obj://b/ck")
+            ck.prefetch()
+            host, _ = ck.restore(like=None)
+        finally:
+            peer_mod.reset()
+            for srv in servers:
+                srv.close()
+        want = {f"w{w}/{k}": a for w in range(3)
+                for k, a in trees[w][f"w{w}"].items()}
+        assert sorted(host) == sorted(want)
+        for k, a in want.items():
+            np.testing.assert_array_equal(np.asarray(host[k]), a)
+        assert ck.last_restore_local_bytes == 0
+        assert ck.last_restore_peer_bytes > 0
+        # rank 0 owns about half the pages at world 2
+        assert 0 < ck.last_restore_wire_bytes \
+            <= 0.60 * ck.last_restore_bytes_read
+        assert (ck.last_restore_peer_bytes + ck.last_restore_wire_bytes
+                == ck.last_restore_bytes_read)
+
     def test_sharded_jax_tree_remote(self, remote):
         tree, _ = TestShardedCheckpoint().make_sharded_tree()
         ck = ShardedCheckpoint("obj://b/ck")

@@ -11,7 +11,6 @@ import pytest
 
 import jax
 
-import bench
 import chip_smoke
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -21,7 +20,7 @@ ROWS = 512
 @pytest.fixture(scope="module")
 def corpus(tmp_path_factory):
     path = str(tmp_path_factory.mktemp("smoke") / "corpus.libsvm")
-    bench.ensure_data(path, 1)  # one 4000-row block, ~2 MB
+    chip_smoke.ensure_data(path, 1)  # one 4000-row block, ~2 MB
     return path
 
 
@@ -132,7 +131,7 @@ def test_stream_hash_ignores_batching_but_not_content(ingested):
     assert changed.hexdigest() != report["hbm_hash"]
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py"])
 def test_cpu_run_is_refused(script):
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO}
     r = subprocess.run([sys.executable, os.path.join(REPO, script)],

@@ -398,6 +398,94 @@ class TestElasticGangAcceptance:
         assert hashlib.sha256(b"\n".join(got)).hexdigest() == baseline
         assert outs["b"], "the victim's prefix should be real work"
 
+    def test_grow_then_leave_resumes_committed_prefix(self):
+        """The elastic 2->3->2 arc: a third member joins mid-epoch,
+        commits a few batches and leaves cleanly; owners take parts
+        over from the committed prefix at both reshards. Every record
+        is consumed exactly once, both epoch bumps reach the gang, and
+        at least one part changes hands mid-part."""
+        import threading
+
+        from dmlc_tpu.rendezvous import RendezvousClient, elastic
+        from dmlc_tpu.rendezvous.service import RendezvousService
+        num_parts, per_part, batch, leave_after = 6, 60, 2, 5
+        ranges = {m: [] for m in "abc"}     # (part, start, end)
+        worlds = []                         # (epoch, world) deliveries
+        stop = threading.Event()
+
+        def consume(cli, out, max_commits=None):
+            while len(out) != max_commits and not stop.is_set():
+                v = cli.view()
+                if v["rank"] is None or v["epoch"] is None:
+                    return
+                if all(elastic.resume_skip(v["progress"], p) >= per_part
+                       for p in range(num_parts)):
+                    return
+                progressed = False
+                for p in elastic.assign_parts(num_parts, v["world"],
+                                              v["rank"]):
+                    start = elastic.resume_skip(v["progress"], p)
+                    if start >= per_part:
+                        continue
+                    end = min(start + batch, per_part)
+                    if cli.commit(p, end, epoch=v["epoch"]):
+                        out.append((p, start, end))
+                        progressed = True
+                    break  # one batch per pass: re-derive ownership
+                if not progressed:
+                    cli.heartbeat()
+                    time.sleep(0.002)
+
+        with RendezvousService(heartbeat_grace_s=30.0) as svc:
+            clis = {m: RendezvousClient("127.0.0.1", svc.port,
+                                        gang="arc", member=m)
+                    for m in "abc"}
+            for cli in clis.values():
+                cli.on_change(lambda v: worlds.append(
+                    (v["epoch"], v["world"])))
+            clis["a"].join()
+            clis["b"].join()
+            threads = {m: threading.Thread(
+                target=consume, args=(clis[m], ranges[m]), daemon=True)
+                for m in "ab"}
+            for t in threads.values():
+                t.start()
+            try:
+                deadline = time.monotonic() + 30
+                while (len(ranges["a"]) + len(ranges["b"])) * batch * 4 \
+                        < num_parts * per_part:
+                    assert time.monotonic() < deadline, \
+                        "the world-2 gang never consumed a quarter"
+                    time.sleep(0.002)
+                clis["c"].join()   # the mid-epoch grow
+                consume(clis["c"], ranges["c"], max_commits=leave_after)
+                clis["c"].leave()  # the clean shrink
+                for m in "ab":
+                    threads[m].join(timeout=60)
+                    assert not threads[m].is_alive(), \
+                        f"member {m!r} hung after the shrink"
+            finally:
+                stop.set()
+        for p in range(num_parts):
+            got = sorted((s, e, m) for m, rs in ranges.items()
+                         for (q, s, e) in rs if q == p)
+            cursor = 0
+            for s, e, m in got:
+                assert s == cursor, \
+                    f"part {p}: gap or overlap at {s} (member {m!r})"
+                cursor = e
+            assert cursor == per_part, f"part {p} stops at {cursor}"
+        assert len(ranges["c"]) == leave_after
+        grow = [e for e, w in worlds if w == 3]
+        assert grow, "the grow to world 3 never reached the gang"
+        assert any(w == 2 and e > grow[0] for e, w in worlds), \
+            "the shrink back to world 2 never reached the gang"
+        owners = {p: {m for m, rs in ranges.items()
+                      for (q, _, _) in rs if q == p}
+                  for p in range(num_parts)}
+        assert any(len(ms) > 1 for ms in owners.values()), \
+            "no part was taken over mid-part"
+
     def test_grow_reshard_visible_on_trace_gang_and_ledger(self):
         import json as _json
         import urllib.request

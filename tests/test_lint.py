@@ -330,8 +330,7 @@ class TestLintGate:
 
     def test_arrow_gate_clean(self):
         # pyarrow imports in dmlc_tpu/ confined to the parquet golden
-        # and the bench corpus makers (the native lane must never
-        # silently lean on pyarrow)
+        # (the native lane must never silently lean on pyarrow)
         findings = lint.arrow_lint(lint.python_files())
         assert findings == [], "\n".join(findings)
 
@@ -349,9 +348,7 @@ class TestLintGate:
     def test_arrow_gate_exempts_golden_and_bench(self):
         golden = os.path.join(lint.REPO, "dmlc_tpu", "data",
                               "parquet_parser.py")
-        bench = os.path.join(lint.REPO, "dmlc_tpu", "bench_suite.py")
         assert lint.arrow_lint([golden]) == []
-        assert lint.arrow_lint([bench]) == []
 
     def test_profile_gate_clean(self):
         # sys._current_frames walks and cProfile/profile/pstats

@@ -342,6 +342,27 @@ class TestSteadyPathEndToEnd:
         py, _ = _drain_padded(uri, "python", 100, 100 * 12)
         _assert_batches_equal(nat, py)
 
+    def test_map_between_parse_and_batch_unfuses(self, tmp_path):
+        # a map stage between the native parse and batch() blocks the
+        # fusion: the same native parse, assembled by the Python fused
+        # pad instead — and the batches stay byte-identical
+        uri = _write_libsvm(tmp_path, rows=2000)
+        built = (Pipeline.from_uri(uri)
+                 .parse(format="libsvm", engine="native",
+                        chunk_size=32 << 10)
+                 .map(lambda b: b, name="unfuse")
+                 .batch(100, pad=True, nnz_bucket=100 * 12).build())
+        unfused = [{k: np.array(v, copy=True) for k, v in b.items()}
+                   for b in built]
+        snap = built.stats()
+        built.close()
+        path = None
+        for st in snap["stages"]:
+            path = st.get("extra", {}).get("assembly_path") or path
+        assert path == "python-fused"
+        py, _ = _drain_padded(uri, "python", 100, 100 * 12)
+        _assert_batches_equal(unfused, py)
+
     def test_full_steady_path_to_device(self, tmp_path):
         uri = _write_libsvm(tmp_path, rows=1500)
         built = (Pipeline.from_uri(uri)

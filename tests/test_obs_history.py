@@ -683,7 +683,7 @@ class TestAnalyze:
         assert any("objstore" in e for e in v["evidence"])
 
     def test_sharded_vs_unsharded_legs_differ_in_evidence(self):
-        """Acceptance: two config-12-style legs may share a bound but
+        """Acceptance: a fused and a sharded leg may share a bound but
         must NOT share evidence — the verdict names the measured
         waits, which differ."""
         fused = obs_analyze.attribute(_snap([
@@ -774,8 +774,7 @@ class TestAnalyze:
     def test_analyze_endpoint_scopes_wire_counters_to_epoch(self):
         """/analyze deltas the wire counters against the previous
         epoch's close: cold-hydration traffic from EARLIER work must
-        not flip a purely local later epoch to wire-bound (the same
-        scoping config 13 applies)."""
+        not flip a purely local later epoch to wire-bound."""
         reg = MetricsRegistry()
         state = {"epoch": 1}
 
@@ -801,33 +800,41 @@ class TestAnalyze:
             assert v2["bound"] != "wire"
             assert not any("objstore" in e for e in v2["evidence"])
 
-    def test_bench_suite_config13_block(self):
-        """The config-13 acceptance body: a short epoch emits a
-        non-empty, schema-valid "analysis" block whose bound is
-        consistent with the measured waits (asserted inside)."""
-        from dmlc_tpu.bench_suite import bench_analyze
-        out = bench_analyze(2)
-        assert out["config"] == "analyze"
-        v = out["analysis"]
-        assert sorted(v) == sorted(obs_analyze.VERDICT_KEYS)
-        assert v["bound"] in obs_analyze.BOUNDS and v["evidence"]
-
-    def test_bench_suite_config13_counters_are_epoch_deltas(self):
-        """Wire counters feeding config 13's verdict are deltas across
-        THE MEASURED EPOCH: remote traffic left in the process-global
-        registry by an earlier config (config 11 in a full-suite run)
-        must not flip a purely local epoch to wire-bound."""
-        from dmlc_tpu.bench_suite import bench_analyze
+    def test_attribute_real_epoch_with_profiler(self, tmp_path):
+        """attribute() on a real CompiledPipeline.stats() epoch and a
+        private sampling profiler's document: the verdict is
+        schema-valid, carries evidence, per-stage waits and weighted
+        hot frames, and never names a bound whose measured wait is
+        zero."""
+        from dmlc_tpu.obs import profile as obs_profile
+        from dmlc_tpu.pipeline import Pipeline
+        uri = _write_libsvm(tmp_path, rows=6000)
+        built = (Pipeline.from_uri(uri)
+                 .parse(format="libsvm", engine="auto", chunk_size=8192)
+                 .batch(256, pad=True, nnz_bucket=256 * 3)
+                 .build())
+        prof = obs_profile.StackProfiler(hz=211)
         try:
-            REGISTRY.counter("objstore.get").inc(5000)
-            REGISTRY.counter("objstore.bytes").inc(50 << 30)
-            REGISTRY.counter("pagestore.miss").inc(10000)
-            out = bench_analyze(2)
-            assert out["analysis"]["bound"] != "wire"
-            assert not any("objstore" in e
-                           for e in out["analysis"]["evidence"])
+            prof.start()
+            snap = built.run_epoch()
+            prof.sample_now(force=True)  # even a sub-period epoch samples
+            doc = prof.to_dict()
         finally:
-            REGISTRY.reset()
+            prof.stop()
+            built.close()
+        v = obs_analyze.attribute(snap, profile_doc=doc)
+        assert sorted(v) == sorted(obs_analyze.VERDICT_KEYS)
+        assert v["bound"] in obs_analyze.BOUNDS
+        assert v["evidence"]
+        assert v["stage_waits"]["stages"]
+        assert v["hot_frames"], "no hot_frames from the sampling profiler"
+        for hf in v["hot_frames"]:
+            assert sorted(hf) == ["frac", "frame", "samples"]
+            assert hf["samples"] > 0 and 0.0 <= hf["frac"] <= 1.0
+        key = {"parse": "parse_s", "assemble": "assemble_s",
+               "xfer": "xfer_s"}.get(v["bound"])
+        if key is not None:
+            assert v["stage_waits"][key] > 0
 
 
 class TestObsctl:

@@ -552,6 +552,93 @@ class TestShardedRowBlockIter:
         assert not os.path.exists(spill_path), \
             "close() must delete the spill file"
 
+    def test_page_spill_tees_on_second_epoch(self, mesh, tmp_path, rng):
+        # no epoch-1 cache ("never"): epoch 1 parses, epoch 2 re-parses
+        # and tees its over-budget rounds to pages, and every epoch
+        # after that replays the pages — all byte-identical
+        p = tmp_path / "d.libsvm"
+        self._write_libsvm(p, rng, 300)
+        it = ShardedRowBlockIter(str(p), mesh, format="libsvm",
+                                 row_bucket=32, nnz_bucket=64,
+                                 prefetch=False,
+                                 agreement_cache_bytes=2048,  # << shard
+                                 spill_dir=str(tmp_path / "spill"),
+                                 first_epoch_cache="never")
+        e1 = self._collect(it)
+        e2 = self._collect(it)
+        assert it.replay_tier == "parse"
+        assert it._round_store is not None
+        assert it._round_store.tier == "pages"
+        replays = [self._collect(it) for _ in range(3)]
+        assert it.replay_tier == "pages"
+        assert (it.replay_epochs, it.page_replay_epochs) == (3, 3)
+        want = self._epoch_hash(e1)
+        assert self._epoch_hash(e2) == want
+        assert all(self._epoch_hash(e) == want for e in replays)
+        it.close()
+
+    @pytest.mark.parametrize("protocol", ["one_vote", "peer_over_budget",
+                                          "per_round"])
+    def test_gang_round_agreement_pads_short_rank(self, mesh, tmp_path,
+                                                  rng, monkeypatch,
+                                                  protocol):
+        # a two-process gang seen from the rank with FEWER rounds: the
+        # peer's answers come from a stand-in allgather. Whichever
+        # epoch-1 protocol the gang agrees on (one vote, or the
+        # per-round done flags when any rank is over its cache
+        # budget), this rank yields the gang's round count, padding
+        # with empty batches, and its steady epoch matches epoch 1.
+        from jax.experimental import multihost_utils
+
+        import dmlc_tpu.parallel.sharded as sharded_mod
+        p = tmp_path / "d.libsvm"
+        self._write_libsvm(p, rng, 200)
+        kw = dict(format="libsvm", row_bucket=32, nnz_bucket=64,
+                  prefetch=False, first_epoch_cache="always")
+        if protocol == "per_round":
+            kw["agreement_cache_bytes"] = 0
+        alone = self._collect(ShardedRowBlockIter(str(p), mesh, **kw))
+        peer_rounds = len(alone) + 3
+        calls = {"vote": 0, "round": 0}
+
+        def allgather(x):
+            x = np.asarray(x)
+            if x.dtype == np.int64:  # the one-vote epoch-1 agreement
+                calls["vote"] += 1
+                cached = 0 if protocol == "peer_over_budget" else 1
+                peer = np.array([cached, peer_rounds, 0, 0], np.int64)
+            else:                    # a per-round done flag
+                peer = np.array([calls["round"] >= peer_rounds,
+                                 False, False])
+                calls["round"] += 1
+            return np.stack([x, peer])
+
+        monkeypatch.setattr(multihost_utils, "process_allgather",
+                            allgather)
+
+        class TwoProcessJax:
+            def __getattr__(self, name):
+                return getattr(jax, name)
+
+            @staticmethod
+            def process_count():
+                return 2
+
+        monkeypatch.setattr(sharded_mod, "jax", TwoProcessJax())
+        it = ShardedRowBlockIter(str(p), mesh, **kw)
+        e1 = self._collect(it)
+        e2 = self._collect(it)
+        assert calls["vote"] == 1
+        assert calls["round"] == (0 if protocol == "one_vote"
+                                  else peer_rounds + 1)
+        assert len(e1) == len(e2) == peer_rounds
+        assert self._epoch_hash(e1[:len(alone)]) == \
+            self._epoch_hash(alone)
+        assert all(int(b["num_rows"].sum()) == 0
+                   for b in e1[len(alone):])
+        assert self._epoch_hash(e2) == self._epoch_hash(e1)
+        it.close()
+
     def test_page_spill_mutation_reparses_then_reearns(self, mesh,
                                                        tmp_path, rng):
         # the mutation contract is tier-independent: a page-armed

@@ -574,8 +574,7 @@ class TestGangAcceptance:
             objstore.configure(None)
         local_hash = hashlib.sha256(payload).hexdigest()
         worker = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "dmlc_tpu", "bench_peer_worker.py")
+            os.path.dirname(os.path.abspath(__file__)), "peer_worker.py")
         out_dir = tmp_path / "gang"
         out_dir.mkdir()
         env = {

@@ -751,62 +751,3 @@ class TestGangCrashAcceptance:
         names = {e.get("name") for e in merged["traceEvents"]}
         assert "gang/restart/worker-1" in names
         assert any(n.startswith("gang/spawn/") for n in names)
-
-
-# ---------------------------------------------------------- bench chaos
-
-class TestBenchChaos:
-    def test_bench_suite_chaos_degrades_not_aborts(
-            self, tmpfile, monkeypatch, capsys):
-        # --chaos arms the plan for the run; a config whose I/O rides
-        # the guarded seams retries through injected faults and still
-        # emits a SUCCESS line (with the chaos accounting), not an
-        # "error" line
-        from dmlc_tpu import bench_suite
-        data = tmpfile("bench.bin", b"y" * 4096)
-        set_policy("io.stream.*",
-                   RetryPolicy(max_attempts=4, sleep=_noop_sleep))
-
-        def chaos_probe(mb, dev):
-            from dmlc_tpu.io.stream import create_stream
-            t0 = time.perf_counter()
-            with create_stream(data, "r") as s:
-                payload = s.read_exact(4096)
-            dt = time.perf_counter() - t0
-            return {"config": "chaos_probe", "gbps": 4096 / dt / 1e9,
-                    "bytes": len(payload)}
-
-        def doomed(mb, dev):
-            inject.fire("bench.doomed")  # always-armed ioerror below
-            return {"config": "doomed", "gbps": 0.0}
-
-        # one main() over BOTH configs (doomed first) so the per-config
-        # delta baselines are exercised across a failing config
-        monkeypatch.setattr(bench_suite, "CONFIGS",
-                            {98: ("doomed", doomed),
-                             99: ("chaos_probe", chaos_probe)})
-        # the suite's compile cache would outlive this test in the worker
-        monkeypatch.setattr("dmlc_tpu.utils.compile_cache."
-                            "place_compile_cache", lambda: "")
-        with pytest.raises(SystemExit) as ei:
-            bench_suite.main([
-                "--mb", "1", "--cold",
-                "--chaos",
-                "site=bench.doomed,fault=ioerror;"
-                "site=io.stream.open,fault=ioerror,times=1;"
-                "site=io.stream.read,fault=ioerror,times=1"])
-        # every config ran, and the failed one fails the suite
-        assert ei.value.code == 1
-        out = [json.loads(line) for line in
-               capsys.readouterr().out.splitlines() if line.strip()]
-        assert len(out) == 2
-        # config 98 aborts (un-retryable by count: every fire raises)
-        assert "error" in out[0]
-        # config 99 degrades gracefully, and its chaos accounting is a
-        # per-config DELTA: the doomed config's injected faults are
-        # not credited to it
-        assert "error" not in out[1]
-        assert out[1]["bytes"] == 4096
-        assert out[1]["chaos"]["injected"] == 2
-        assert out[1]["chaos"]["retries"]["io.stream.open"] == 1
-        assert "bench.doomed" not in out[1]["chaos"]["retries"]

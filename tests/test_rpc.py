@@ -400,7 +400,7 @@ class TestTracingOffOverhead:
 
 class TestGangTraceAcceptance:
     def test_two_rank_gang_merged_trace_is_flow_linked(self, tmp_path):
-        """A REAL 2-process gang (bench_peer_worker, no jax) with
+        """A REAL 2-process gang (tests/peer_worker.py, no jax) with
         tracing + rendezvous on: the merged timeline must contain at
         least one flow-linked client/server span pair for EVERY edge
         type — peer /pages (cross-process), objstore GET (emulator),
@@ -415,8 +415,7 @@ class TestGangTraceAcceptance:
         finally:
             objstore.configure(None)
         worker = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "dmlc_tpu", "bench_peer_worker.py")
+            os.path.dirname(os.path.abspath(__file__)), "peer_worker.py")
         out_dir = tmp_path / "gang"
         out_dir.mkdir()
         trace_dir = tmp_path / "traces"
